@@ -10,7 +10,10 @@ TPU adaptation notes (vs. the usual CUDA flash kernel):
   * fully-masked KV tiles are skipped with @pl.when (causal upper triangle
     and out-of-window tiles), halving the causal FLOPs.
 
-Validated against `ref.flash_attention_reference` in interpret mode on CPU.
+Off the served path: `ops.flash_attention` is the jnp implementation,
+and this kernel's `(1, bq, 1, D)` blocks (a size-1 second-minor dim) are
+refused by Mosaic's tiling rule, so it runs in interpret mode only
+(`interpret=True`). Validated against `ref.flash_attention_reference`.
 """
 from __future__ import annotations
 
@@ -82,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
                      "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, kv_len=None,
                            q_offset=0, softmax_scale=None, block_q=128,
-                           block_k=128, interpret=None):
+                           block_k=128, interpret=False):
     """q: (B, Sq, H, D); k, v: (B, Skv, KV, D). kv_len unsupported here
     (engine prefills exact-length sequences); q_offset must be static."""
     assert kv_len is None, "pallas prefill kernel expects exact-length batches"
@@ -94,8 +97,6 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, kv_len=None,
     bk = min(block_k, Skv)
     assert Sq % bq == 0 and Skv % bk == 0
     nq, nk = Sq // bq, Skv // bk
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     grid = (B, KV, G, nq, nk)
     kernel = functools.partial(

@@ -1,39 +1,34 @@
-"""Public jit'd kernel entry points with backend selection.
+"""Attention entry points, with the implementation picked by platform.
 
-Backends:
-  'ref'       pure-jnp chunked oracle (default; lowers cleanly under GSPMD on
-              any platform — this is what the dry-run compiles)
-  'pallas'    Pallas TPU kernels; on CPU they run in interpret mode (used by
-              kernel tests), on TPU they compile to Mosaic.
+On a TPU the paged ops (`paged_attention`, `paged_prefill`) run the
+compiled Pallas kernels; on any other platform they run the pure-jnp
+oracles in `ref.py`, which stay the test oracle. Dense attention
+(`flash_attention`, `decode_attention`) is always the jnp implementation,
+XLA-compiled on every platform. There is no interpreter fallback: the
+Pallas kernels interpret only when a caller passes `interpret=True`.
 
-Select globally via `set_backend` or per-call via `backend=`.
+`dispatched` counts, at trace time, which implementation each op lowered
+to, keyed by (op, impl) — a compiled step's count shows what it runs.
 """
 from __future__ import annotations
 
+import collections
+
+import jax
+
 from repro.kernels import ref as _ref
 
-_BACKEND = "ref"
+dispatched: collections.Counter = collections.Counter()
 
 
-def set_backend(name: str) -> None:
-    global _BACKEND
-    assert name in ("ref", "pallas"), name
-    _BACKEND = name
-
-
-def get_backend() -> str:
-    return _BACKEND
+def paged_impl() -> str:
+    """'pallas' on a TPU, else 'ref'."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, kv_len=None, q_offset=0,
-                    q_chunk=512, kv_chunk=512, softmax_scale=None,
-                    backend=None):
-    b = backend or _BACKEND
-    if b == "pallas":
-        from repro.kernels import flash_prefill
-        return flash_prefill.flash_attention_pallas(
-            q, k, v, causal=causal, window=window, kv_len=kv_len,
-            q_offset=q_offset, softmax_scale=softmax_scale)
+                    q_chunk=512, kv_chunk=512, softmax_scale=None):
+    dispatched["flash_attention", "ref"] += 1
     return _ref.flash_attention_reference(
         q, k, v, causal=causal, window=window, kv_len=kv_len,
         q_offset=q_offset, q_chunk=q_chunk, kv_chunk=kv_chunk,
@@ -41,16 +36,17 @@ def flash_attention(q, k, v, *, causal=True, window=0, kv_len=None, q_offset=0,
 
 
 def decode_attention(q, k_cache, v_cache, kv_len, *, window=0,
-                     softmax_scale=None, backend=None):
+                     softmax_scale=None):
+    dispatched["decode_attention", "ref"] += 1
     return _ref.decode_attention_reference(
         q, k_cache, v_cache, kv_len, window=window,
         softmax_scale=softmax_scale)
 
 
-def paged_attention(q, kv_pool, block_table, kv_len, *, softmax_scale=None,
-                    backend=None):
-    b = backend or _BACKEND
-    if b == "pallas":
+def paged_attention(q, kv_pool, block_table, kv_len, *, softmax_scale=None):
+    impl = paged_impl()
+    dispatched["paged_attention", impl] += 1
+    if impl == "pallas":
         from repro.kernels import paged_attention as _pa
         return _pa.paged_attention_pallas(
             q, kv_pool, block_table, kv_len, softmax_scale=softmax_scale)
@@ -59,8 +55,7 @@ def paged_attention(q, kv_pool, block_table, kv_len, *, softmax_scale=None,
 
 
 def paged_prefill(q, kv_pool, block_table, seg_ids, q_pos, kv_len, *,
-                  host_pool=None, tier=None, tq=8, softmax_scale=None,
-                  backend=None):
+                  host_pool=None, tier=None, tq=8, softmax_scale=None):
     """Segmented prefill/decode attention straight over the paged pool(s).
 
     q: (T, H, D) flat token batch — per-request segments each padded to a
@@ -69,8 +64,10 @@ def paged_prefill(q, kv_pool, block_table, seg_ids, q_pos, kv_len, *,
     (S, MAXB); seg_ids/q_pos: (T,); kv_len: (S,). With `tier` (S,) bool,
     a True segment's blocks are read from `host_pool`. Returns (T, H, D).
     """
-    b = backend or _BACKEND
-    if b == "pallas":
+    impl = paged_impl()
+    op = "paged_prefill" if tier is None else "paged_prefill_tiered"
+    dispatched[op, impl] += 1
+    if impl == "pallas":
         from repro.kernels import paged_prefill as _pp
         return _pp.paged_prefill_pallas(
             q, kv_pool, block_table, seg_ids, q_pos, kv_len,

@@ -4,8 +4,14 @@ One query token per sequence attends over KV stored in a *single pooled
 tensor* of fixed-size blocks (paper §4: one physical tensor, logical
 per-layer allocation), addressed through a block table.
 
+Pool layout is head-major, `(NB, 2, KV, BS, D)`: one grid step fetches
+one KV head of one page as a `(1, 2, 1, BS, D)` block, whose last two
+dims are the whole `(BS, D)` page slice — the shape Mosaic's tiling
+rule accepts for any block size and head dim.
+
 TPU adaptation of the CUDA PagedAttention kernel:
-  * the block table and per-sequence lengths are **scalar-prefetched**
+  * the block table (flattened to 1-D so SMEM holds it unpadded) and
+    per-sequence lengths are **scalar-prefetched**
     (pltpu.PrefetchScalarGridSpec) so the BlockSpec index_map itself chases
     the page table — the DMA engine gathers KV blocks HBM->VMEM directly,
     there is no software gather;
@@ -15,7 +21,9 @@ TPU adaptation of the CUDA PagedAttention kernel:
   * all G = H/KV query heads of a KV group ride in one tile so the MXU sees
     a (G, D) x (D, BS) matmul per page instead of G vector products.
 
-Validated against `ref.paged_attention_reference` in interpret mode.
+Compiled by Mosaic on a TPU; elsewhere callers must pass
+`interpret=True` (Pallas refuses a compiled call off-TPU). Validated
+against `ref.paged_attention_reference` in interpret mode.
 """
 from __future__ import annotations
 
@@ -46,8 +54,8 @@ def _paged_kernel(tab_ref, len_ref, q_ref, pool_ref, o_ref, m_sc, l_sc,
     @pl.when(block_live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale     # (G, D)
-        k = pool_ref[0, :, 0, 0, :].astype(jnp.float32)  # (BS, D)
-        v = pool_ref[0, :, 1, 0, :].astype(jnp.float32)  # (BS, D)
+        k = pool_ref[0, 0, 0].astype(jnp.float32)       # (BS, D)
+        v = pool_ref[0, 1, 0].astype(jnp.float32)       # (BS, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G,BS)
         pos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -71,16 +79,14 @@ def _paged_kernel(tab_ref, len_ref, q_ref, pool_ref, o_ref, m_sc, l_sc,
 @functools.partial(jax.jit,
                    static_argnames=("softmax_scale", "interpret"))
 def paged_attention_pallas(q, kv_pool, block_table, kv_len, *,
-                           softmax_scale=None, interpret=None):
-    """q: (B, H, D); kv_pool: (NB, BS, 2, KV, D); block_table: (B, MAXB)
+                           softmax_scale=None, interpret=False):
+    """q: (B, H, D); kv_pool: (NB, 2, KV, BS, D); block_table: (B, MAXB)
     int32; kv_len: (B,) int32. Returns (B, H, D)."""
     B, H, D = q.shape
-    NB, BS, _, KV, _ = kv_pool.shape
+    _, _, KV, BS, _ = kv_pool.shape
     MAXB = block_table.shape[1]
     G = H // KV
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     grid = (B, KV, MAXB)
     kernel = functools.partial(_paged_kernel, bs=BS, n_blocks=MAXB,
@@ -96,8 +102,9 @@ def paged_attention_pallas(q, kv_pool, block_table, kv_len, *,
                          lambda b, kh, ib, tab, lens: (b, kh, 0, 0)),
             # page-table chase: physical block id comes from the prefetched
             # table; KV head rides in the block
-            pl.BlockSpec((1, BS, 2, 1, D),
-                         lambda b, kh, ib, tab, lens: (tab[b, ib], 0, 0, kh, 0)),
+            pl.BlockSpec((1, 2, 1, BS, D),
+                         lambda b, kh, ib, tab, lens:
+                             (tab[b * MAXB + ib], 0, kh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, kh, ib, tab, lens: (b, kh, 0, 0)),
@@ -112,5 +119,5 @@ def paged_attention_pallas(q, kv_pool, block_table, kv_len, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
-    )(block_table, kv_len, q4, kv_pool)
+    )(block_table.reshape(-1), kv_len, q4, kv_pool)
     return out.reshape(B, H, D)

@@ -32,7 +32,15 @@ the exception; a production TPU deployment would pin the host tier in
 device-mappable memory or pre-stage, which this repo models at the
 block-manager level.
 
-Validated against `ref.paged_prefill_reference` in interpret mode.
+Layouts Mosaic tiles cleanly: the pool is head-major `(NB, 2, KV, BS,
+D)` (a grid step fetches one head of one page, a whole `(BS, D)` slice),
+block tables ride SMEM flattened to 1-D, and q/o are laid out
+`(KV, n_q_tiles, TQ*G, D)` by the wrapper so a tile is already the 2-D
+`(TQ*G, D)` matmul operand — no in-kernel reshape.
+
+Compiled by Mosaic on a TPU; elsewhere callers must pass
+`interpret=True` (Pallas refuses a compiled call off-TPU). Validated
+against `ref.paged_prefill_reference` in interpret mode.
 """
 from __future__ import annotations
 
@@ -46,14 +54,13 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _prefill_body(q_ref, o_ref, m_sc, l_sc, acc_sc, k, v, *, ib, bs, g, tq,
-                  scale, q0, kv_len):
+def _prefill_body(q_ref, m_sc, l_sc, acc_sc, k, v, *, ib, bs, g, scale, q0,
+                  kv_len):
     """Shared online-softmax update for one (q_tile, kv_block) pair.
     k/v: (BS, D) f32 already selected from the right pool. `ib` is passed
     in: pl.program_id is read once at kernel top level (this jax version
     cannot lower it inside a pl.when body in interpret mode)."""
-    D = k.shape[-1]
-    q = q_ref[0, :, 0].astype(jnp.float32).reshape(tq * g, D) * scale
+    q = q_ref[0, 0].astype(jnp.float32) * scale        # (tq*g, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (tq*g, BS)
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
@@ -72,7 +79,7 @@ def _prefill_body(q_ref, o_ref, m_sc, l_sc, acc_sc, k, v, *, ib, bs, g, tq,
         preferred_element_type=jnp.float32)
 
 
-def _init_finalize(o_ref, m_sc, l_sc, acc_sc, *, ib, g, tq):
+def _init_finalize(o_ref, m_sc, l_sc, acc_sc, *, ib):
     @pl.when(ib == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
@@ -81,8 +88,7 @@ def _init_finalize(o_ref, m_sc, l_sc, acc_sc, *, ib, g, tq):
 
     def _finalize():
         l = jnp.maximum(l_sc[...], 1e-30)
-        out = acc_sc[...] / l[:, None]
-        o_ref[0, :, 0] = out.reshape(tq, g, out.shape[-1]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
     return _finalize
 
 
@@ -90,7 +96,7 @@ def _paged_prefill_kernel(tab_ref, tseg_ref, tqpos_ref, len_ref, q_ref,
                           pool_ref, o_ref, m_sc, l_sc, acc_sc, *, bs, g, tq,
                           n_blocks, scale):
     it, ib = pl.program_id(1), pl.program_id(2)
-    finalize = _init_finalize(o_ref, m_sc, l_sc, acc_sc, ib=ib, g=g, tq=tq)
+    finalize = _init_finalize(o_ref, m_sc, l_sc, acc_sc, ib=ib)
     seg = tseg_ref[it]
     kv_len = len_ref[seg]
     q0 = tqpos_ref[it]
@@ -98,10 +104,10 @@ def _paged_prefill_kernel(tab_ref, tseg_ref, tqpos_ref, len_ref, q_ref,
 
     @pl.when(live)
     def _compute():
-        k = pool_ref[0, :, 0, 0, :].astype(jnp.float32)   # (BS, D)
-        v = pool_ref[0, :, 1, 0, :].astype(jnp.float32)
-        _prefill_body(q_ref, o_ref, m_sc, l_sc, acc_sc, k, v, ib=ib, bs=bs,
-                      g=g, tq=tq, scale=scale, q0=q0, kv_len=kv_len)
+        k = pool_ref[0, 0, 0].astype(jnp.float32)         # (BS, D)
+        v = pool_ref[0, 1, 0].astype(jnp.float32)
+        _prefill_body(q_ref, m_sc, l_sc, acc_sc, k, v, ib=ib, bs=bs, g=g,
+                      scale=scale, q0=q0, kv_len=kv_len)
 
     pl.when(ib == n_blocks - 1)(finalize)
 
@@ -114,7 +120,7 @@ def _paged_prefill_kernel_tiered(tab_ref, tier_ref, tseg_ref, tqpos_ref,
     reads its blocks from the HOST pool (tier flag), everything else from
     the device pool. Both candidate blocks ride the tile (2x KV DMA)."""
     it, ib = pl.program_id(1), pl.program_id(2)
-    finalize = _init_finalize(o_ref, m_sc, l_sc, acc_sc, ib=ib, g=g, tq=tq)
+    finalize = _init_finalize(o_ref, m_sc, l_sc, acc_sc, ib=ib)
     seg = tseg_ref[it]
     kv_len = len_ref[seg]
     q0 = tqpos_ref[it]
@@ -123,14 +129,14 @@ def _paged_prefill_kernel_tiered(tab_ref, tier_ref, tseg_ref, tqpos_ref,
 
     @pl.when(live)
     def _compute():
-        kd = dpool_ref[0, :, 0, 0, :].astype(jnp.float32)
-        vd = dpool_ref[0, :, 1, 0, :].astype(jnp.float32)
-        kh = hpool_ref[0, :, 0, 0, :].astype(jnp.float32)
-        vh = hpool_ref[0, :, 1, 0, :].astype(jnp.float32)
+        kd = dpool_ref[0, 0, 0].astype(jnp.float32)
+        vd = dpool_ref[0, 1, 0].astype(jnp.float32)
+        kh = hpool_ref[0, 0, 0].astype(jnp.float32)
+        vh = hpool_ref[0, 1, 0].astype(jnp.float32)
         k = jnp.where(is_host, kh, kd)
         v = jnp.where(is_host, vh, vd)
-        _prefill_body(q_ref, o_ref, m_sc, l_sc, acc_sc, k, v, ib=ib, bs=bs,
-                      g=g, tq=tq, scale=scale, q0=q0, kv_len=kv_len)
+        _prefill_body(q_ref, m_sc, l_sc, acc_sc, k, v, ib=ib, bs=bs, g=g,
+                      scale=scale, q0=q0, kv_len=kv_len)
 
     pl.when(ib == n_blocks - 1)(finalize)
 
@@ -139,26 +145,27 @@ def _paged_prefill_kernel_tiered(tab_ref, tier_ref, tseg_ref, tqpos_ref,
                                              "interpret"))
 def paged_prefill_pallas(q, kv_pool, block_table, seg_ids, q_pos, kv_len, *,
                          host_pool=None, tier=None, tq=8, softmax_scale=None,
-                         interpret=None):
+                         interpret=False):
     """q: (T, H, D) flat segment-padded token batch, T % tq == 0, with each
-    tq-row tile entirely inside one segment; kv_pool: (NB, BS, 2, KV, D);
+    tq-row tile entirely inside one segment; kv_pool: (NB, 2, KV, BS, D);
     block_table: (S, MAXB) int32; seg_ids/q_pos: (T,) int32; kv_len: (S,)
-    int32. Optional host_pool (NBH, BS, 2, KV, D) + tier (S,) selects the
+    int32. Optional host_pool (NBH, 2, KV, BS, D) + tier (S,) selects the
     pool per segment. Returns (T, H, D).
 
     The caller guarantees the chunk's own KV is already scattered into the
     pool — the kernel reads prefix AND in-chunk keys through the table,
     with the causal mask (q_pos >= k_pos) handling the in-chunk tail."""
     T, H, D = q.shape
-    BS, KV = kv_pool.shape[1], kv_pool.shape[3]
+    KV, BS = kv_pool.shape[2], kv_pool.shape[3]
     MAXB = block_table.shape[1]
     G = H // KV
     NT = T // tq
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
-    q4 = q.reshape(NT, tq, KV, G, D)
+    # (KV, NT, tq*G, D): row r of a tile is token r // G, head r % G
+    qt = q.reshape(NT, tq, KV, G, D).transpose(2, 0, 1, 3, 4) \
+        .reshape(KV, NT, tq * G, D)
+    tab = block_table.reshape(-1)
     tile_seg = seg_ids.reshape(NT, tq)[:, 0].astype(jnp.int32)
     tile_qpos = q_pos.reshape(NT, tq)[:, 0].astype(jnp.int32)
     grid = (KV, NT, MAXB)
@@ -168,26 +175,26 @@ def paged_prefill_pallas(q, kv_pool, block_table, seg_ids, q_pos, kv_len, *,
         pltpu.VMEM((tq * G, D), jnp.float32),
     ]
     q_spec = pl.BlockSpec(
-        (1, tq, 1, G, D), lambda kh, it, ib, *pf: (it, 0, kh, 0, 0))
+        (1, 1, tq * G, D), lambda kh, it, ib, *pf: (kh, it, 0, 0))
     out_spec = pl.BlockSpec(
-        (1, tq, 1, G, D), lambda kh, it, ib, *pf: (it, 0, kh, 0, 0))
+        (1, 1, tq * G, D), lambda kh, it, ib, *pf: (kh, it, 0, 0))
+    out_shape = jax.ShapeDtypeStruct(qt.shape, q.dtype)
 
     if tier is None:
         kernel = functools.partial(_paged_prefill_kernel, bs=BS, g=G, tq=tq,
                                    n_blocks=MAXB, scale=scale)
         pool_spec = pl.BlockSpec(
-            (1, BS, 2, 1, D),
+            (1, 2, 1, BS, D),
             lambda kh, it, ib, tab, tseg, tqp, lens:
-                (tab[tseg[it], ib], 0, 0, kh, 0))
+                (tab[tseg[it] * MAXB + ib], 0, kh, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=grid,
             in_specs=[q_spec, pool_spec], out_specs=out_spec,
             scratch_shapes=scratch)
         out = pl.pallas_call(
-            kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+            kernel, grid_spec=grid_spec, out_shape=out_shape,
             interpret=interpret,
-        )(block_table, tile_seg, tile_qpos, kv_len, q4, kv_pool)
+        )(tab, tile_seg, tile_qpos, kv_len, qt, kv_pool)
     else:
         kernel = functools.partial(_paged_prefill_kernel_tiered, bs=BS, g=G,
                                    tq=tq, n_blocks=MAXB, scale=scale)
@@ -196,21 +203,21 @@ def paged_prefill_pallas(q, kv_pool, block_table, seg_ids, q_pos, kv_len, *,
         # `where` discards it
         nbd, nbh = kv_pool.shape[0], host_pool.shape[0]
         dpool_spec = pl.BlockSpec(
-            (1, BS, 2, 1, D),
+            (1, 2, 1, BS, D),
             lambda kh, it, ib, tab, tier_, tseg, tqp, lens:
-                (jnp.minimum(tab[tseg[it], ib], nbd - 1), 0, 0, kh, 0))
+                (jnp.minimum(tab[tseg[it] * MAXB + ib], nbd - 1), 0, kh, 0, 0))
         hpool_spec = pl.BlockSpec(
-            (1, BS, 2, 1, D),
+            (1, 2, 1, BS, D),
             lambda kh, it, ib, tab, tier_, tseg, tqp, lens:
-                (jnp.minimum(tab[tseg[it], ib], nbh - 1), 0, 0, kh, 0))
+                (jnp.minimum(tab[tseg[it] * MAXB + ib], nbh - 1), 0, kh, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=grid,
             in_specs=[q_spec, dpool_spec, hpool_spec], out_specs=out_spec,
             scratch_shapes=scratch)
         out = pl.pallas_call(
-            kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+            kernel, grid_spec=grid_spec, out_shape=out_shape,
             interpret=interpret,
-        )(block_table, tier.astype(jnp.int32), tile_seg, tile_qpos, kv_len,
-          q4, kv_pool, host_pool)
-    return out.reshape(T, H, D)
+        )(tab, tier.astype(jnp.int32), tile_seg, tile_qpos, kv_len,
+          qt, kv_pool, host_pool)
+    return out.reshape(KV, NT, tq, G, D).transpose(1, 2, 0, 3, 4) \
+        .reshape(T, H, D)

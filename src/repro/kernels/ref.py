@@ -159,6 +159,14 @@ def decode_attention_reference(q, k_cache, v_cache, kv_len, *, window=0,
     )
 
 
+def _pages_to_seq(pages):
+    """Gathered head-major pages (N, MAXB, 2, KV, BS, D) -> K and V as
+    token-major sequences (N, MAXB*BS, KV, D)."""
+    n, maxb, _, kv, bs, d = pages.shape
+    seq = pages.transpose(0, 1, 4, 2, 3, 5).reshape(n, maxb * bs, 2, kv, d)
+    return seq[:, :, 0], seq[:, :, 1]
+
+
 def paged_prefill_reference(q, kv_pool, block_table, seg_ids, q_pos, kv_len,
                             *, host_pool=None, tier=None, tq=8,
                             softmax_scale=None):
@@ -178,7 +186,7 @@ def paged_prefill_reference(q, kv_pool, block_table, seg_ids, q_pos, kv_len,
 
     q:           (T, H, D)   flat token batch, T % tq == 0 (padding rows
                  allowed; their outputs are garbage the caller discards)
-    kv_pool:     (NB, BS, 2, KV, D) device pool; [..., 0/1, :, :] = K/V
+    kv_pool:     (NB, 2, KV, BS, D) device pool; [:, 0/1] = K/V
     block_table: (S, MAXB) int32 physical block ids per segment
     seg_ids:     (T,) int32 segment of each token
     q_pos:       (T,) int32 absolute position of each token in its sequence
@@ -191,7 +199,7 @@ def paged_prefill_reference(q, kv_pool, block_table, seg_ids, q_pos, kv_len,
     """
     T, H, D = q.shape
     S, MAXB = block_table.shape
-    BS, KV = kv_pool.shape[1], kv_pool.shape[3]
+    KV, BS = kv_pool.shape[2], kv_pool.shape[3]
     G = H // KV
     NT = T // tq
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
@@ -200,12 +208,11 @@ def paged_prefill_reference(q, kv_pool, block_table, seg_ids, q_pos, kv_len,
     # a host-resident segment's ids index the HOST pool and vice versa —
     # clamp the not-applicable gather into range, `where` discards it
     g = kv_pool[jnp.minimum(tab_t, kv_pool.shape[0] - 1)]
-    if tier is not None:                     # (NT, MAXB, BS, 2, KV, D)
+    if tier is not None:                     # (NT, MAXB, 2, KV, BS, D)
         gh = host_pool[jnp.minimum(tab_t, host_pool.shape[0] - 1)]
         tt = tier[tile_seg]
         g = jnp.where(tt[:, None, None, None, None, None], gh, g)
-    k = g[:, :, :, 0].reshape(NT, MAXB * BS, KV, D)
-    v = g[:, :, :, 1].reshape(NT, MAXB * BS, KV, D)
+    k, v = _pages_to_seq(g)                  # (NT, MAXB*BS, KV, D)
     qh = (q * scale).reshape(NT, tq, KV, G, D)
     logits = jnp.einsum("ntkgd,nskd->nkgts", qh, k).astype(jnp.float32)
     k_pos = jnp.arange(MAXB * BS)
@@ -223,21 +230,15 @@ def paged_attention_reference(q, kv_pool, block_table, kv_len, *,
     """Decode GQA attention over a paged KV pool (oracle for the Pallas kernel).
 
     q:           (B, H, D)       one query token per sequence
-    kv_pool:     (N_blocks, BS, 2, KV, D)  single pooled tensor (paper §4),
-                 [..., 0, :, :] = K, [..., 1, :, :] = V
+    kv_pool:     (N_blocks, 2, KV, BS, D)  single pooled tensor (paper §4),
+                 [:, 0] = K, [:, 1] = V
     block_table: (B, MAX_BLOCKS) int32 physical block ids (padding: any id —
                  masked out by kv_len)
     kv_len:      (B,) valid token count per sequence
     returns      (B, H, D)
     """
-    B, H, D = q.shape
-    NB, BS = kv_pool.shape[0], kv_pool.shape[1]
-    KV = kv_pool.shape[3]
-    MAX_BLOCKS = block_table.shape[1]
-    # Gather per-sequence K/V: (B, MAX_BLOCKS, BS, 2, KV, D)
-    gathered = kv_pool[block_table]
-    k = gathered[:, :, :, 0].reshape(B, MAX_BLOCKS * BS, KV, D)
-    v = gathered[:, :, :, 1].reshape(B, MAX_BLOCKS * BS, KV, D)
+    # Gather per-sequence K/V: (B, MAX_BLOCKS, 2, KV, BS, D)
+    k, v = _pages_to_seq(kv_pool[block_table])
     out = mha_reference(q[:, None], k, v, causal=False, kv_len=kv_len,
                         softmax_scale=softmax_scale)
     return out[:, 0]

@@ -26,10 +26,8 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
 
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_rows", "interpret"))
-def rmsnorm_pallas(x, w, *, eps=1e-6, block_rows=128, interpret=None):
+def rmsnorm_pallas(x, w, *, eps=1e-6, block_rows=128, interpret=False):
     """x: (..., d); w: (d,). Returns rmsnorm(x) * w in x.dtype."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     orig_shape = x.shape
     d = x.shape[-1]
     rows = 1

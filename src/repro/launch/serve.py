@@ -23,14 +23,40 @@ wedge@0.2:r1:dur=0.3` or `random:SEED[:n=N]` — serving/faults.py);
 `--liveness-timeout` arms missing-heartbeat detection, `--shed-overload`
 turns wedging overload into typed request shedding. The drain report
 then includes the recovery trace and kill/retry/shed counters.
+
+The model serves in its config's dtype (bf16 for every shipped config).
+With `--replicas N > 1` replica i is pinned to `jax.devices()[i]` — one
+engine per chip — and N may not exceed the device count (on a CPU host,
+`XLA_FLAGS=--xla_force_host_platform_device_count=N` provides N devices).
+JAX's persistent compilation cache goes where `JAX_COMPILATION_CACHE_DIR`
+says, else to `.jax_cache/` at the checkout root.
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import os
+import pathlib
 import statistics
 
 import numpy as np
+
+_CHECKOUT_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def setup_compile_cache(root: pathlib.Path = _CHECKOUT_ROOT) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory: `JAX_COMPILATION_CACHE_DIR` when set (JAX reads
+    it itself; nothing else is set here), otherwise the fixed `.jax_cache/`
+    under `root` — a fixed path, since the path is part of what makes a
+    later run find the entries. Entry points call this; module import
+    never does."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(root / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main():
@@ -110,8 +136,11 @@ def main():
                  "needs at least one unique token")
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
+    setup_compile_cache()
+    if args.replicas > len(jax.devices()):
+        ap.error(f"--replicas {args.replicas} exceeds the "
+                 f"{len(jax.devices())} device(s): one engine per device")
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
-    cfg = dataclasses.replace(cfg, dtype="float32")
     rng = np.random.RandomState(args.seed)
     shared = [int(x) for x in
               rng.randint(0, cfg.vocab_size, args.shared_len)]
@@ -151,9 +180,12 @@ def main():
         for line in plan.describe():
             print(f"  {line}")
     # every replica loads the SAME weights (one PRNG seed): a cluster is
-    # N copies of one model behind a router, not N different models
-    engines = [LayerKVEngine(cfg, None, sc, rng=jax.random.PRNGKey(args.seed))
-               for _ in range(args.replicas)]
+    # N copies of one model behind a router, not N different models —
+    # each on its own device when there are several
+    devices = jax.devices()[:args.replicas] if args.replicas > 1 else [None]
+    engines = [LayerKVEngine(cfg, None, sc, rng=jax.random.PRNGKey(args.seed),
+                             device=d)
+               for d in devices]
 
     # submit everything up front (arrivals dispatch as the shared clock
     # reaches them) and pump the cluster one event at a time, printing

@@ -72,13 +72,17 @@ class LayerKVEngine(CoreDelegateMixin):
     def __init__(self, cfg: ModelConfig, params=None,
                  ec: Optional[ServeConfig] = None,
                  hw: HWProfile = TPU_V5E,
-                 predictor: Optional[LengthPredictor] = None, rng=None):
+                 predictor: Optional[LengthPredictor] = None, rng=None,
+                 device=None):
+        """`device`: the one device this engine's params, KV pools and
+        steps live on (None = the default device) — one engine per chip
+        when replicas serve behind a `ClusterSession`."""
         self.cfg = cfg
         self.ec = (ec or ServeConfig.for_engine()).validate()
         ndb = self.ec.num_device_blocks or 128  # 0 = backend default
         self.ex = PagedExecutor(cfg, params, ndb,
                                 self.ec.num_host_blocks, self.ec.block_size,
-                                rng=rng)
+                                rng=rng, device=device)
         self.L = cfg.n_layers
         self.bm = LayerwiseBlockManager(ndb, self.ec.num_host_blocks,
                                         self.ec.block_size, self.L,
@@ -301,9 +305,11 @@ class LayerKVEngine(CoreDelegateMixin):
         return True
 
     def _evict_newest(self, exclude=()) -> bool:
-        """Push the newest request's device layers to host to make room.
-        Shared prefix blocks are copied out (detach), never pulled from
-        under the requests still mapping them."""
+        """Push the newest request's device layers to host to make room,
+        as many as the host pool takes. Shared prefix blocks are copied
+        out (detach), never pulled from under the requests still mapping
+        them. Returns whether any layer moved; the victim's host-layer
+        count is kept exact even when only some of its layers moved."""
         excl = set(exclude)
         for r in sorted(self.decoding, key=lambda q: -q.prefill_start):
             if r.rid in excl:
@@ -311,15 +317,17 @@ class LayerKVEngine(CoreDelegateMixin):
             dev = self.bm.layers_on(r.rid, DEVICE)
             if not dev:
                 continue
+            moved = False
             for l in dev:
                 a = self.bm.allocation(r.rid, l)
                 if self.core.host_free() < len(a.blocks):
-                    return False
+                    break
                 src, dst = self.bm.move_layer(r.rid, l, HOST, detach=True)
                 self.ex.copy_blocks("device", "host", src, dst)
                 self.off.proactive_offload(self.now, a.num_tokens, 1)
+                moved = True
             self.host_layers[r.rid] = len(self.bm.layers_on(r.rid, HOST))
-            return True
+            return moved
         return False
 
     # ------------------------------------------------------ decode iteration

@@ -1,12 +1,14 @@
 """Real JAX executor for the serving engine: paged KV pools + jitted steps.
 
 Physical layout follows the paper's §4: ONE pooled tensor per memory tier
-(device / host), shared by all layers — `(num_blocks, block_size, 2, KV, hd)`
-— so any physical block can hold any (request, layer) slice; logical
-placement lives in the block manager. Each pool carries ONE extra physical
-block (`trash_block`) that the block manager never hands out: padded batch
-rows scatter their garbage KV there, which is what lets every jitted entry
-point run on shape-bucketed (power-of-two padded) batches.
+(device / host), shared by all layers — head-major `(num_blocks, 2, KV,
+block_size, hd)`, so a Pallas kernel fetches one head of one page as a
+whole `(block_size, hd)` tile — and any physical block can hold any
+(request, layer) slice; logical placement lives in the block manager.
+Each pool carries ONE extra physical block (`trash_block`) that the block
+manager never hands out: padded batch rows scatter their garbage KV
+there, which is what lets every jitted entry point run on shape-bucketed
+(power-of-two padded) batches.
 
 Bucketed-shape contract: `prefill` pads the prompt buffer, `decode` the
 batch width R, and `mixed_step` the chunk rows Tc / chunk segments Sc /
@@ -50,6 +52,14 @@ def _round_up(n, m):
     return -(-n // m) * m
 
 
+def _scatter_tokens(pool, blk, off, k, v):
+    """Write per-token KV rows k/v (N, KV, hd) into head-major pool pages
+    at (blk[i], off[i]) slots. `pool[blk, 0, :, off]` indexes as (N, KV,
+    hd): the advanced indices around the head slice come first."""
+    pool = pool.at[blk, 0, :, off].set(k.astype(pool.dtype))
+    return pool.at[blk, 1, :, off].set(v.astype(pool.dtype))
+
+
 def _bucket(n: int, lo: int = 1) -> int:
     """Smallest power-of-two >= n (and >= lo) — the jit shape bucket."""
     b = lo
@@ -85,12 +95,20 @@ class PagedExecutor:
     decided upstream by `SchedulerCore`/`LayerwiseBlockManager`."""
 
     def __init__(self, cfg: ModelConfig, params, num_device_blocks: int,
-                 num_host_blocks: int, block_size: int, rng=None):
+                 num_host_blocks: int, block_size: int, rng=None,
+                 device=None):
+        """`device` pins params, both pools and therefore every jitted
+        step (they follow their committed inputs) to one device; None
+        leaves them on the default device."""
         assert cfg.family in ("dense", "moe"), cfg.family
         self.cfg = cfg
         self.model = build_model(cfg)
-        self.params = params if params is not None else self.model.init(
-            rng if rng is not None else jax.random.PRNGKey(0))
+        if params is None:
+            with jax.default_device(device):
+                params = self.model.init(
+                    rng if rng is not None else jax.random.PRNGKey(0))
+        self.params = params if device is None \
+            else jax.device_put(params, device)
         hd = cfg.resolved_head_dim
         dt = jnp.dtype(cfg.dtype)
         self.block_size = block_size
@@ -100,9 +118,11 @@ class PagedExecutor:
         # scatter writes; the block manager never allocates it and no
         # block table with kv_len > 0 ever reads it
         self.device_pool = jnp.zeros(
-            (num_device_blocks + 1, block_size, 2, cfg.n_kv_heads, hd), dt)
+            (num_device_blocks + 1, 2, cfg.n_kv_heads, block_size, hd), dt,
+            device=device)
         self.host_pool = jnp.zeros(
-            (num_host_blocks + 1, block_size, 2, cfg.n_kv_heads, hd), dt)
+            (num_host_blocks + 1, 2, cfg.n_kv_heads, block_size, hd), dt,
+            device=device)
         self._decode_fn = jax.jit(self._paged_decode,
                                   donate_argnames=("dpool",))
         self._prefill_fn = jax.jit(
@@ -157,11 +177,10 @@ class PagedExecutor:
         """Write one layer's KV (S_pad, KV, hd) into `pool` blocks.
         block_ids: (nb,) int32; S_pad == nb * block_size."""
         nb = block_ids.shape[0]
-        BS = pool.shape[1]
-        kr = k.reshape(nb, BS, *k.shape[1:]).astype(pool.dtype)
-        vr = v.reshape(nb, BS, *v.shape[1:]).astype(pool.dtype)
-        kv = jnp.stack([kr, vr], axis=2)  # (nb, BS, 2, KV, hd)
-        return pool.at[block_ids].set(kv)
+        BS = pool.shape[3]
+        kv = jnp.stack([k, v], axis=1).astype(pool.dtype)  # (S_pad,2,KV,hd)
+        kv = kv.reshape(nb, BS, *kv.shape[1:]).transpose(0, 2, 3, 1, 4)
+        return pool.at[block_ids].set(kv)  # (nb, 2, KV, BS, hd) pages
 
     def write_layer(self, tier: str, block_ids: List[int], k, v):
         ids = jnp.asarray(block_ids, jnp.int32)
@@ -177,8 +196,7 @@ class PagedExecutor:
     def _scatter_slice(self, pool, blk_ids, offs, k, v):
         """Write C tokens of one layer's KV into per-token (block, offset)
         slots — the partial-block append used by chunked prefill."""
-        pool = pool.at[blk_ids, offs, 0].set(k.astype(pool.dtype))
-        return pool.at[blk_ids, offs, 1].set(v.astype(pool.dtype))
+        return _scatter_tokens(pool, blk_ids, offs, k, v)
 
     def write_layer_slice(self, tier: str, block_ids: List[int],
                           token_offset: int, k, v):
@@ -207,9 +225,10 @@ class PagedExecutor:
         nb = len(block_ids)
         live = nb if kv_valid is None else min(
             _round_up(kv_valid, self.block_size) // self.block_size, nb)
-        gathered = pool[jnp.asarray(block_ids[:live], jnp.int32)]
-        k = gathered[:, :, 0].reshape(live * self.block_size, *pool.shape[3:])
-        v = gathered[:, :, 1].reshape(live * self.block_size, *pool.shape[3:])
+        pages = pool[jnp.asarray(block_ids[:live], jnp.int32)]
+        _, _, KV, BS, hd = pool.shape
+        seq = pages.transpose(0, 3, 1, 2, 4).reshape(live * BS, 2, KV, hd)
+        k, v = seq[:, 0], seq[:, 1]
         if live < nb:
             pad = [(0, (nb - live) * self.block_size), (0, 0), (0, 0)]
             k = jnp.pad(k, pad)
@@ -332,7 +351,7 @@ class PagedExecutor:
         ctx+1 after the in-step scatter). sample_idx: (Sb,) flat position
         each output row samples; is_chunk selects pad-vocab masking to
         mirror the two-call paths bit-for-bit. Returns
-        (next_tokens (Sb,), dpool, hpool)."""
+        (logits (Sb, V), dpool, hpool)."""
         cfg = self.cfg
         Tc = c_seg.shape[0]
         Rb = d_kvlens.shape[0]
@@ -347,15 +366,9 @@ class PagedExecutor:
             q, k, v = layers.qkv_proj(cfg, lp["attn"], h)
             q = layers.apply_rope(cfg, q, positions)
             k = layers.apply_rope(cfg, k, positions)
-            dpool = dpool.at[blk_dev[l], off, 0].set(
-                k[0].astype(dpool.dtype))
-            dpool = dpool.at[blk_dev[l], off, 1].set(
-                v[0].astype(dpool.dtype))
+            dpool = _scatter_tokens(dpool, blk_dev[l], off, k[0], v[0])
             if has_host:
-                hpool = hpool.at[blk_host[l], off, 0].set(
-                    k[0].astype(hpool.dtype))
-                hpool = hpool.at[blk_host[l], off, 1].set(
-                    v[0].astype(hpool.dtype))
+                hpool = _scatter_tokens(hpool, blk_host[l], off, k[0], v[0])
             parts = []
             if Tc:
                 parts.append(ops.paged_prefill(
@@ -383,19 +396,27 @@ class PagedExecutor:
         # decode samples stay raw (as _paged_decode does)
         logits = jnp.where(is_chunk[:, None],
                            _mask_pad_logits(cfg, logits), logits)
-        return jnp.argmax(logits, axis=-1), dpool, hpool
+        return logits, dpool, hpool
 
     def mixed_step(self, chunks: List[MixedChunk],
                    decodes: List[MixedDecode]) -> np.ndarray:
+        """Run one fused iteration (`mixed_logits`) and return the
+        (n_chunks + n_decodes,) argmax'd next tokens (chunk rows are only
+        meaningful for a request's final chunk)."""
+        logits = self.mixed_logits(chunks, decodes)
+        return np.asarray(jnp.argmax(logits, axis=-1))
+
+    def mixed_logits(self, chunks: List[MixedChunk],
+                     decodes: List[MixedDecode]) -> jax.Array:
         """Run one fused iteration: all prefill chunks + the decode batch
         in one forward (one weight stream). Chunk KV and decode KV are
         scattered into the pools inside the step; attention reads the
         pools directly. Shapes are power-of-two bucketed (chunk rows Tc,
         chunk segments Sc, decode width Rb, output rows Sb; table widths
         round to 8 blocks) with padded rows writing the trash block, so
-        steady state reuses compiled signatures. Returns the
-        (n_chunks + n_decodes,) argmax'd next tokens (chunk rows are only
-        meaningful for a request's final chunk)."""
+        steady state reuses compiled signatures. Returns the on-device
+        (n_chunks + n_decodes, V) next-token logits: a chunk's row is
+        its last token's."""
         TQ = MIXED_TQ
         BS = self.block_size
         L = self.cfg.n_layers
@@ -463,7 +484,7 @@ class PagedExecutor:
             sample_idx[n_c + j] = t
         has_host = bool(c_tier.any())
         self._note_trace("mixed", (Tc, Sc, Rb, Sb, MAXBc, MAXBd, has_host))
-        toks_out, self.device_pool, self.host_pool = self._mixed_forward(
+        logits, self.device_pool, self.host_pool = self._mixed_forward(
             self.params, jnp.asarray(tokens), jnp.asarray(q_pos),
             jnp.asarray(off), jnp.asarray(blk_dev), jnp.asarray(blk_host),
             jnp.asarray(c_seg), jnp.asarray(q_pos[:Tc]),
@@ -472,7 +493,7 @@ class PagedExecutor:
             jnp.asarray(d_kvlens), jnp.asarray(sample_idx),
             jnp.asarray(is_chunk), self.device_pool, self.host_pool,
             has_host)
-        return np.asarray(toks_out)[:n_c + n_d]
+        return logits[:n_c + n_d]
 
     # --------------------------------------------------------------- decode
     def _paged_decode(self, params, tokens, tables, kv_lens, dpool):
@@ -495,10 +516,7 @@ class PagedExecutor:
                 cfg, lp["attn"], h, None, None, None, positions)
             # scatter the new token's KV into its block
             blk = tables[l][r_idx, cur_block]  # (R,)
-            dpool = dpool.at[blk, cur_off, 0].set(
-                k[:, 0].astype(dpool.dtype))
-            dpool = dpool.at[blk, cur_off, 1].set(
-                v[:, 0].astype(dpool.dtype))
+            dpool = _scatter_tokens(dpool, blk, cur_off, k[:, 0], v[:, 0])
             o = ops.paged_attention(q[:, 0], dpool, tables[l], kv_lens + 1)
             x = x + layers.attn_out(cfg, lp["attn"], o[:, None])
             h = layers.apply_norm(cfg, lp["mlp_norm"], x)
@@ -515,6 +533,13 @@ class PagedExecutor:
 
     def decode(self, tokens: List[int], tables: np.ndarray,
                kv_lens: List[int]) -> List[int]:
+        """One decode iteration (`decode_logits`); returns the R argmax'd
+        next tokens."""
+        logits = self.decode_logits(tokens, tables, kv_lens)
+        return [int(t) for t in np.asarray(jnp.argmax(logits, axis=-1))]
+
+    def decode_logits(self, tokens: List[int], tables: np.ndarray,
+                      kv_lens: List[int]) -> jax.Array:
         """One decode iteration. tables: (L, R, MAXB) int32 into the DEVICE
         pool (caller guarantees residency). The batch width R is padded to
         a power-of-two bucket and the table width MAXB to 8-block
@@ -522,7 +547,8 @@ class PagedExecutor:
         the ref backend; 8 blocks bounds the waste while retracing at most
         once per 8 blocks of context growth) — padded rows carry
         trash-block tables (kv_len 0), so novel batch shapes reuse
-        compiled signatures instead of retracing mid-serving."""
+        compiled signatures instead of retracing mid-serving. Returns the
+        on-device (R, V) next-token logits."""
         R = len(tokens)
         L, _, maxb = tables.shape
         Rb = _bucket(R)
@@ -537,4 +563,4 @@ class PagedExecutor:
         logits, self.device_pool = self._decode_fn(
             self.params, jnp.asarray(toks), jnp.asarray(tab),
             jnp.asarray(lens), self.device_pool)
-        return [int(t) for t in jnp.argmax(logits[:R], axis=-1)]
+        return logits[:R]

@@ -137,3 +137,30 @@ def test_engine_layerkv_admits_earlier():
     ttft_l = np.mean([r.ttft for r in eng_l.done])
     ttft_v = np.mean([r.ttft for r in eng_v.done])
     assert ttft_l <= ttft_v + 1e-9
+
+
+@pytest.mark.slow
+def test_engine_partial_eviction_lossless():
+    """A host pool too small to take a whole victim: eviction moves only
+    some of its layers (17-block layers, 64 host blocks), the request
+    must still be reloaded before it decodes, and tokens match the
+    request-wise engine."""
+    cfg = dataclasses.replace(get_smoke_config("granite-3-2b"),
+                              dtype="float32", n_layers=4)
+    r0 = np.random.RandomState(4)
+    prompts = [[int(x) for x in r0.randint(0, cfg.vocab_size, 256)]
+               for _ in range(8)]
+    outs = []
+    for policy, ndb, nhb in (("vllm", 2048, 64), ("layerkv", 256, 64)):
+        eng = LayerKVEngine(
+            cfg, None,
+            EngineConfig(policy=policy, slo_aware=False,
+                         num_device_blocks=ndb, num_host_blocks=nhb,
+                         block_size=16),
+            rng=jax.random.PRNGKey(42))
+        reqs = [Request(rid=f"r{i}", prompt_len=256, output_len=12,
+                        arrival=i * 1e-6, prompt=p)
+                for i, p in enumerate(prompts)]
+        outs.append({r.rid: r.generated for r in eng.run(reqs)})
+    assert any(t.kind == "offload" for t in eng.off.ledger.log)
+    assert outs[0] == outs[1]
