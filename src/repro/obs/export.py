@@ -47,8 +47,6 @@ def _track_events(tracer, pid: int, label: str) -> List[dict]:
 
     for ev in tracer.events:
         args = dict(ev.get("args") or {})
-        if "wall" in ev:
-            args["wall_s"] = ev["wall"]
         row: dict = {"name": ev["type"], "cat": "serving",
                      "pid": pid, "tid": tid_of(ev.get("rid")),
                      "args": args}

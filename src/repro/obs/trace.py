@@ -48,13 +48,13 @@ the NEW first token too. Causes (docs/ARCHITECTURE.md "Observability"):
   recompute_requeue    re-queued after a recompute preemption, not yet
                        re-examined
 
-Timestamps are the backend's virtual clock (seconds); the engine
-additionally stamps wall-clock seconds on every event (`wall_clock`
-hook) so real-execution traces carry both timelines.
+Timestamps are the backend's virtual clock (seconds), which the
+scheduler's decisions run on. The engine's wall-clock record is its
+profiler spans (`repro.obs.spans`), on the device trace's clock.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from repro.core import DEVICE, HOST
 
@@ -100,14 +100,10 @@ class Tracer:
         self.events: List[dict] = []
         self._attr: Dict[str, _Attr] = {}
         self._pause_t: Dict[str, float] = {}
-        # engine hook: () -> wall seconds, stamped as ev["wall"]
-        self.wall_clock: Optional[Callable[[], float]] = None
 
     # ------------------------------------------------------ raw emission
     def _emit(self, ev: dict) -> None:
         assert ev["type"] in EVENT_TYPES, ev["type"]
-        if self.wall_clock is not None:
-            ev["wall"] = self.wall_clock()
         self.events.append(ev)
 
     def span(self, etype: str, rid: Optional[str], t0: float, t1: float,

@@ -33,7 +33,6 @@ tests/test_fused.py, tests/test_session.py).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 import jax.numpy as jnp
@@ -43,6 +42,7 @@ from repro.configs.base import ModelConfig
 from repro.core import DEVICE, HOST, LayerwiseBlockManager, OffloadEngine, \
     SLOScheduler
 from repro.core.predictor import HistogramPredictor, LengthPredictor
+from repro.obs.spans import span
 from repro.serving.costmodel import CostModel, HWProfile, TPU_V5E
 from repro.serving.executor import MixedChunk, MixedDecode, PagedExecutor
 from repro.serving.request import Phase, Request
@@ -100,10 +100,7 @@ class LayerKVEngine(CoreDelegateMixin):
         # one registry per engine: the executor's jit-retrace counters
         # share the core's namespace so a single snapshot() has both
         self.ex.registry = self.core.registry
-        if self.core.tracer is not None:
-            # real-execution traces carry wall time next to the virtual
-            # clock (the virtual clock stays primary so streams merge)
-            self.core.tracer.wall_clock = time.perf_counter
+        self._steps = 0     # iterations run: the `sched.step` span's stat
         self._chunk_bufs: Dict[str, tuple] = {}  # rid -> cached (k, v)
 
     # --------------------------------------------- shared-core delegation
@@ -137,47 +134,49 @@ class LayerKVEngine(CoreDelegateMixin):
 
     # -------------------------------------------------------------- prefill
     def _do_prefill(self, r: Request) -> bool:
-        alloc = self.core.alloc_prefill(r)
-        if alloc is None:
-            return False
-        retain, off = alloc
+        with span("sched.prefill", rid=r.rid, tokens=r.prompt_len):
+            alloc = self.core.alloc_prefill(r)
+            if alloc is None:
+                return False
+            retain, off = alloc
 
-        if r.prefill_done > 0:
-            # prefix-cache hit: run the uncached suffix as ONE chunk
-            # against the shared prefix blocks (q_offset causal masking);
-            # compute for the cached tokens is skipped entirely
-            c, p = r.prefill_remaining, r.prefill_done
-            self._run_chunk(r, c)
-            self.now += self.cost.chunk_prefill_time(c, p)
-        else:
-            pad = self.bm.blocks_for_tokens(r.prompt_len) \
-                * self.ec.block_size
-            next_tok, k, v = self.ex.prefill(r.prompt, pad)
-            for l in retain:
-                a = self.bm.allocation(r.rid, l)
-                self.ex.write_layer("device", a.blocks, k[l], v[l])
-            for l in off:
-                a = self.bm.allocation(r.rid, l)
-                self.ex.write_layer("host", a.blocks, k[l], v[l])
-            if off:
-                from repro.core import OffloadPlan
-                self.off.prefill_offload_done(
-                    self.now, r.prompt_len,
-                    OffloadPlan(retain, off, len(retain)))
-            self.now += self.cost.prefill_time(r.prompt_len)
-            r.prefill_done = r.prompt_len
-            r.n_chunks += 1
-            r.generated.append(next_tok)
-            if self.ec.prefix_cache and r.prompt:
-                self.bm.register_prefix(r.rid, r.prompt)
-        r.prefill_start = r.prefill_start if r.prefill_start >= 0 else self.now
-        if r.first_token_time < 0:  # survives replica-kill restart
-            r.first_token_time = self.now
-        r.tokens_out = 1
-        r.note_token(self.now)
-        r.phase = Phase.DECODE
-        self.decoding.append(r)
-        return True
+            if r.prefill_done > 0:
+                # prefix-cache hit: run the uncached suffix as ONE chunk
+                # against the shared prefix blocks (q_offset causal
+                # masking); compute for the cached tokens is skipped
+                c, p = r.prefill_remaining, r.prefill_done
+                self._run_chunk(r, c)
+                self.now += self.cost.chunk_prefill_time(c, p)
+            else:
+                pad = self.bm.blocks_for_tokens(r.prompt_len) \
+                    * self.ec.block_size
+                next_tok, k, v = self.ex.prefill(r.prompt, pad)
+                for l in retain:
+                    a = self.bm.allocation(r.rid, l)
+                    self.ex.write_layer("device", a.blocks, k[l], v[l])
+                for l in off:
+                    a = self.bm.allocation(r.rid, l)
+                    self.ex.write_layer("host", a.blocks, k[l], v[l])
+                if off:
+                    from repro.core import OffloadPlan
+                    self.off.prefill_offload_done(
+                        self.now, r.prompt_len,
+                        OffloadPlan(retain, off, len(retain)))
+                self.now += self.cost.prefill_time(r.prompt_len)
+                r.prefill_done = r.prompt_len
+                r.n_chunks += 1
+                r.generated.append(next_tok)
+                if self.ec.prefix_cache and r.prompt:
+                    self.bm.register_prefix(r.rid, r.prompt)
+            if r.prefill_start < 0:
+                r.prefill_start = self.now
+            if r.first_token_time < 0:  # survives replica-kill restart
+                r.first_token_time = self.now
+            r.tokens_out = 1
+            r.note_token(self.now)
+            r.phase = Phase.DECODE
+            self.decoding.append(r)
+            return True
 
     # ------------------------------------------------------- chunked prefill
     def _gather_buffers(self, r: Request):
@@ -230,7 +229,8 @@ class LayerKVEngine(CoreDelegateMixin):
             self.bm.register_prefix(r.rid, r.prompt, upto=r.prefill_done)
         if r.prefill_complete:
             self._chunk_bufs.pop(r.rid, None)
-            r.generated.append(int(jnp.argmax(logits)))
+            with span("exec.chunk.wait"):
+                r.generated.append(int(jnp.argmax(logits)))
         else:
             self._chunk_bufs[r.rid] = (
                 kbuf.at[:, p:p + c].set(kc.astype(kbuf.dtype)),
@@ -250,27 +250,28 @@ class LayerKVEngine(CoreDelegateMixin):
         for r in sel:
             for l in list(self.bm.tables[r.rid]):
                 self.bm.extend_layer(r.rid, l, 1)
-        chunks: List[MixedChunk] = []
-        for r, c in chunk_work:
-            p = r.prefill_done
-            nb_live = -(-(p + c) // self.ec.block_size)
-            tabs, tiers = [], []
-            for l in range(self.L):
-                a = self.bm.allocation(r.rid, l)
-                tabs.append(a.blocks[:nb_live])
-                tiers.append(a.pool == HOST)
-            chunks.append(MixedChunk(tokens=r.prompt[p:p + c], offset=p,
-                                     tables=tabs, tiers=tiers))
-        decodes: List[MixedDecode] = []
-        for r in sel:
-            ctx = r.prompt_len + r.tokens_out - 1
-            tabs = []
-            for l in range(self.L):
-                a = self.bm.allocation(r.rid, l)
-                assert a.pool == DEVICE
-                tabs.append(a.blocks)
-            decodes.append(MixedDecode(token=r.generated[-1], ctx=ctx,
-                                       tables=tabs))
+        with span("exec.mixed.prep"):
+            chunks: List[MixedChunk] = []
+            for r, c in chunk_work:
+                p = r.prefill_done
+                nb_live = -(-(p + c) // self.ec.block_size)
+                tabs, tiers = [], []
+                for l in range(self.L):
+                    a = self.bm.allocation(r.rid, l)
+                    tabs.append(a.blocks[:nb_live])
+                    tiers.append(a.pool == HOST)
+                chunks.append(MixedChunk(tokens=r.prompt[p:p + c], offset=p,
+                                         tables=tabs, tiers=tiers))
+            decodes: List[MixedDecode] = []
+            for r in sel:
+                ctx = r.prompt_len + r.tokens_out - 1
+                tabs = []
+                for l in range(self.L):
+                    a = self.bm.allocation(r.rid, l)
+                    assert a.pool == DEVICE
+                    tabs.append(a.blocks)
+                decodes.append(MixedDecode(token=r.generated[-1], ctx=ctx,
+                                           tables=tabs))
         out = self.ex.mixed_step(chunks, decodes)
         for i, (r, c) in enumerate(chunk_work):
             n_off = len(self.bm.layers_on(r.rid, HOST))
@@ -292,17 +293,23 @@ class LayerKVEngine(CoreDelegateMixin):
     def _ensure_device(self, r: Request) -> bool:
         """Promote every host-resident layer of r to device (h2d). Returns
         False when blocks run out (request pauses this iteration)."""
-        for l in self.bm.layers_on(r.rid, HOST):
-            a = self.bm.allocation(r.rid, l)
-            need = len(a.blocks)
-            if self.bm.num_free(DEVICE) < need:
+        with span("sched.kv.reload", rid=r.rid) as sp:
+            layers = self.bm.layers_on(r.rid, HOST)
+            moved = 0
+            for l in layers:
+                a = self.bm.allocation(r.rid, l)
+                if self.bm.num_free(DEVICE) < len(a.blocks):
+                    break
+                src, dst = self.bm.move_layer(r.rid, l, DEVICE)
+                self.ex.copy_blocks("host", "device", src, dst)
+                self.off.ledger.submit(
+                    self.now, self.cost.kv_bytes(a.num_tokens, 1), "reload")
+                moved += 1
+            sp.set_metadata(layers=moved)
+            if moved < len(layers):
                 return False
-            src, dst = self.bm.move_layer(r.rid, l, DEVICE)
-            self.ex.copy_blocks("host", "device", src, dst)
-            self.off.ledger.submit(
-                self.now, self.cost.kv_bytes(a.num_tokens, 1), "reload")
-        self.host_layers[r.rid] = 0
-        return True
+            self.host_layers[r.rid] = 0
+            return True
 
     def _evict_newest(self, exclude=()) -> bool:
         """Push the newest request's device layers to host to make room,
@@ -310,68 +317,75 @@ class LayerKVEngine(CoreDelegateMixin):
         out (detach), never pulled from under the requests still mapping
         them. Returns whether any layer moved; the victim's host-layer
         count is kept exact even when only some of its layers moved."""
-        excl = set(exclude)
-        for r in sorted(self.decoding, key=lambda q: -q.prefill_start):
-            if r.rid in excl:
-                continue
-            dev = self.bm.layers_on(r.rid, DEVICE)
-            if not dev:
-                continue
-            moved = False
-            for l in dev:
-                a = self.bm.allocation(r.rid, l)
-                if self.core.host_free() < len(a.blocks):
-                    break
-                src, dst = self.bm.move_layer(r.rid, l, HOST, detach=True)
-                self.ex.copy_blocks("device", "host", src, dst)
-                self.off.proactive_offload(self.now, a.num_tokens, 1)
-                moved = True
-            self.host_layers[r.rid] = len(self.bm.layers_on(r.rid, HOST))
-            return moved
-        return False
+        with span("sched.kv.evict") as sp:
+            excl = set(exclude)
+            for r in sorted(self.decoding, key=lambda q: -q.prefill_start):
+                if r.rid in excl:
+                    continue
+                dev = self.bm.layers_on(r.rid, DEVICE)
+                if not dev:
+                    continue
+                moved = 0
+                for l in dev:
+                    a = self.bm.allocation(r.rid, l)
+                    if self.core.host_free() < len(a.blocks):
+                        break
+                    src, dst = self.bm.move_layer(r.rid, l, HOST,
+                                                  detach=True)
+                    self.ex.copy_blocks("device", "host", src, dst)
+                    self.off.proactive_offload(self.now, a.num_tokens, 1)
+                    moved += 1
+                self.host_layers[r.rid] = len(self.bm.layers_on(r.rid,
+                                                                HOST))
+                sp.set_metadata(rid=r.rid, layers=moved)
+                return moved > 0
+            sp.set_metadata(rid="none", layers=0)
+            return False
 
     # ------------------------------------------------------ decode iteration
     def _select_runnable(self, allow_empty: bool = False) -> List[Request]:
         """Pick this iteration's decode batch: device-resident or promotable
         requests with room to grow, most-behind-on-TPOT first."""
-        sel: List[Request] = []
-        reserved = 0  # growth blocks earmarked for already-selected requests
-        for r in sorted(self.decoding,
-                        key=lambda q: q.tpot_slo - q.current_tpot(self.now)):
-            sel_ids = [q.rid for q in sel] + [r.rid]
+        with span("sched.select") as sp:
+            sel: List[Request] = []
+            reserved = 0  # growth blocks earmarked for selected requests
+            for r in sorted(self.decoding, key=lambda q:
+                            q.tpot_slo - q.current_tpot(self.now)):
+                sel_ids = [q.rid for q in sel] + [r.rid]
 
-            def _need(r: Request = r) -> int:
-                """Promotion blocks + growth blocks for r this iteration."""
-                need = 0
-                for l in self.bm.layers_on(r.rid, HOST):
-                    a = self.bm.allocation(r.rid, l)
-                    need += len(a.blocks)
-                    if a.num_tokens % self.ec.block_size == 0:
-                        need += 1
-                for l in self.bm.layers_on(r.rid, DEVICE):
-                    a = self.bm.allocation(r.rid, l)
-                    if a.num_tokens % self.ec.block_size == 0:
-                        need += 1
-                return need
-            while self.bm.num_free(DEVICE) - reserved < _need():
-                if not self._evict_newest(exclude=sel_ids):
-                    break
-            if self.bm.num_free(DEVICE) - reserved < _need():
-                continue  # pause this iteration
-            growth = _need()
-            if self.host_layers.get(r.rid, 0):
-                if not self._ensure_device(r):
-                    continue
-                # promotion blocks were consumed; growth remains earmarked
-                growth = sum(
-                    1 for l in self.bm.layers_on(r.rid, DEVICE)
-                    if self.bm.allocation(r.rid, l).num_tokens
-                    % self.ec.block_size == 0)
-            reserved += growth
-            sel.append(r)
-        if not sel and not allow_empty:
-            raise RuntimeError("engine wedged: no runnable request")
-        return sel
+                def _need(r: Request = r) -> int:
+                    """Promotion + growth blocks for r this iteration."""
+                    need = 0
+                    for l in self.bm.layers_on(r.rid, HOST):
+                        a = self.bm.allocation(r.rid, l)
+                        need += len(a.blocks)
+                        if a.num_tokens % self.ec.block_size == 0:
+                            need += 1
+                    for l in self.bm.layers_on(r.rid, DEVICE):
+                        a = self.bm.allocation(r.rid, l)
+                        if a.num_tokens % self.ec.block_size == 0:
+                            need += 1
+                    return need
+                while self.bm.num_free(DEVICE) - reserved < _need():
+                    if not self._evict_newest(exclude=sel_ids):
+                        break
+                if self.bm.num_free(DEVICE) - reserved < _need():
+                    continue  # pause this iteration
+                growth = _need()
+                if self.host_layers.get(r.rid, 0):
+                    if not self._ensure_device(r):
+                        continue
+                    # promotion blocks were consumed; growth stays earmarked
+                    growth = sum(
+                        1 for l in self.bm.layers_on(r.rid, DEVICE)
+                        if self.bm.allocation(r.rid, l).num_tokens
+                        % self.ec.block_size == 0)
+                reserved += growth
+                sel.append(r)
+            sp.set_metadata(rows=len(sel))
+            if not sel and not allow_empty:
+                raise RuntimeError("engine wedged: no runnable request")
+            return sel
 
     def _run_decode(self, sel: List[Request]) -> float:
         """Grow allocations, run one real decode step over `sel`, append the
@@ -380,14 +394,19 @@ class LayerKVEngine(CoreDelegateMixin):
         for r in sel:
             for l in list(self.bm.tables[r.rid]):
                 self.bm.extend_layer(r.rid, l, 1)
-        maxb = max(len(self.bm.allocation(r.rid, 0).blocks) for r in sel)
-        R = len(sel)
-        tables = np.zeros((self.L, R, maxb), np.int32)
-        for i, r in enumerate(sel):
-            for l in range(self.L):
-                a = self.bm.allocation(r.rid, l)
-                assert a.pool == DEVICE
-                tables[l, i, :len(a.blocks)] = a.blocks
+        with span("exec.decode.prep") as sp:
+            maxb = max(len(self.bm.allocation(r.rid, 0).blocks)
+                       for r in sel)
+            R = len(sel)
+            tables = np.zeros((self.L, R, maxb), np.int32)
+            slots = 0
+            for i, r in enumerate(sel):
+                for l in range(self.L):
+                    a = self.bm.allocation(r.rid, l)
+                    assert a.pool == DEVICE
+                    tables[l, i, :len(a.blocks)] = a.blocks
+                    slots += len(a.blocks)
+            sp.set_metadata(slots=slots)
         kv_lens = [r.prompt_len + r.tokens_out - 1 for r in sel]
         toks = [r.generated[-1] for r in sel]
         new_toks = self.ex.decode(toks, tables, kv_lens)
@@ -401,26 +420,32 @@ class LayerKVEngine(CoreDelegateMixin):
         # the generation cap backstops runaway requests whose target EOS
         # position exceeds the engine's per-request budget
         cap = self.ec.max_tokens_per_request
-        for r in list(self.decoding):
-            if r.tokens_out >= min(r.output_len, cap):
-                r.finish_time = self.now
-                r.phase = Phase.FINISHED
-                self.bm.free_request(r.rid)
-                self.core.release(r)
-                self.predictor.observe(r.output_len)
-                self.decoding.remove(r)
-                self.done.append(r)
-                if self.core.tracer is not None:
-                    self.core.tracer.finish(r, self.now)
+        with span("sched.retire") as sp:
+            finished = 0
+            for r in list(self.decoding):
+                if r.tokens_out >= min(r.output_len, cap):
+                    finished += 1
+                    r.finish_time = self.now
+                    r.phase = Phase.FINISHED
+                    self.bm.free_request(r.rid)
+                    self.core.release(r)
+                    self.predictor.observe(r.output_len)
+                    self.decoding.remove(r)
+                    self.done.append(r)
+                    if self.core.tracer is not None:
+                        self.core.tracer.finish(r, self.now)
+            sp.set_metadata(finished=finished)
 
     # ---------------------------------------------------------------- step
     def step(self) -> bool:
         """One scheduler iteration. Returns False when fully idle."""
-        out = self._step_chunked() if self.ec.chunked \
-            else self._step_exclusive()
-        if self.core.sanitizer is not None:
-            self.core.sanitizer.check(self.core)
-        return out
+        self._steps += 1
+        with span("sched.step", step=self._steps):
+            out = self._step_chunked() if self.ec.chunked \
+                else self._step_exclusive()
+            if self.core.sanitizer is not None:
+                self.core.sanitizer.check(self.core)
+            return out
 
     def _step_exclusive(self) -> bool:
         """Exclusive-prefill iteration (vLLM 0.5.5 semantics)."""

@@ -36,6 +36,7 @@ from repro.kernels import ops
 from repro.models import build_model, layers
 from repro.models.model import _mask_pad_logits
 from repro.obs.registry import MetricsRegistry
+from repro.obs.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -58,6 +59,16 @@ def _scatter_tokens(pool, blk, off, k, v):
     hd): the advanced indices around the head slice come first."""
     pool = pool.at[blk, 0, :, off].set(k.astype(pool.dtype))
     return pool.at[blk, 1, :, off].set(v.astype(pool.dtype))
+
+
+def _program(name: str):
+    """Give a served jitted program the stable name `name`, whatever
+    the method is called: the device trace's "XLA Modules" line shows
+    its device time as `jit_<name>`, and the benchmark reads it there."""
+    def named(fn):
+        fn.__name__ = name
+        return fn
+    return named
 
 
 def _bucket(n: int, lo: int = 1) -> int:
@@ -123,11 +134,12 @@ class PagedExecutor:
         self.host_pool = jnp.zeros(
             (num_host_blocks + 1, 2, cfg.n_kv_heads, block_size, hd), dt,
             device=device)
+        # bytes of one block of either pool (the pools share a layout)
+        self.block_nbytes = self.device_pool.nbytes \
+            // self.device_pool.shape[0]
         self._decode_fn = jax.jit(self._paged_decode,
                                   donate_argnames=("dpool",))
-        self._prefill_fn = jax.jit(
-            functools.partial(self.model.prefill, dropless=True),
-            static_argnames=())
+        self._prefill_fn = jax.jit(self._prefill)
         # retrace accounting: every novel (entry point, shape bucket)
         # signature is one XLA compile mid-serving — the bucketing above
         # exists to keep these counters flat in steady state. Counts
@@ -151,6 +163,10 @@ class PagedExecutor:
                      fn, sig)
 
     # -------------------------------------------------------------- prefill
+    @_program("serve_prefill")
+    def _prefill(self, params, batch, cache):
+        return self.model.prefill(params, batch, cache, dropless=True)
+
     def prefill(self, prompt: List[int], pad_to: int):
         """Run one request's prefill (B=1). `pad_to` is bucketed to the
         next power of two so novel prompt lengths reuse a compiled shape.
@@ -159,20 +175,23 @@ class PagedExecutor:
         valid (callers slice what they need)."""
         S = len(prompt)
         pad_to = _bucket(pad_to, 16)
-        self._note_trace("prefill", (pad_to,))
-        toks = np.zeros((1, pad_to), np.int32)
-        toks[0, :S] = prompt
-        batch = {"tokens": jnp.asarray(toks),
-                 "prompt_len": jnp.asarray([S], jnp.int32)}
-        cache = self.model.init_cache(1, pad_to, self.cfg.dtype)
-        logits, cache = self._prefill_fn(self.params, batch, cache)
-        next_tok = int(jnp.argmax(logits[0]))
-        k = cache["k"][:, 0]  # (L, S_bucket, KV, hd)
-        v = cache["v"][:, 0]
+        with span("exec.prefill.launch", tokens=S, tokens_padded=pad_to):
+            self._note_trace("prefill", (pad_to,))
+            toks = np.zeros((1, pad_to), np.int32)
+            toks[0, :S] = prompt
+            batch = {"tokens": jnp.asarray(toks),
+                     "prompt_len": jnp.asarray([S], jnp.int32)}
+            cache = self.model.init_cache(1, pad_to, self.cfg.dtype)
+            logits, cache = self._prefill_fn(self.params, batch, cache)
+            k = cache["k"][:, 0]  # (L, S_bucket, KV, hd)
+            v = cache["v"][:, 0]
+        with span("exec.prefill.wait"):
+            next_tok = int(jnp.argmax(logits[0]))
         return next_tok, k, v
 
     # ---------------------------------------------------------- pool writes
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+    @_program("kv_write")
     def _scatter_layer(self, pool, block_ids, k, v):
         """Write one layer's KV (S_pad, KV, hd) into `pool` blocks.
         block_ids: (nb,) int32; S_pad == nb * block_size."""
@@ -183,16 +202,22 @@ class PagedExecutor:
         return pool.at[block_ids].set(kv)  # (nb, 2, KV, BS, hd) pages
 
     def write_layer(self, tier: str, block_ids: List[int], k, v):
-        ids = jnp.asarray(block_ids, jnp.int32)
-        S_pad = len(block_ids) * self.block_size
-        k = k[:S_pad]
-        v = v[:S_pad]
-        if tier == "device":
-            self.device_pool = self._scatter_layer(self.device_pool, ids, k, v)
-        else:
-            self.host_pool = self._scatter_layer(self.host_pool, ids, k, v)
+        nb = len(block_ids)
+        with span("exec.kv.write", tier=tier, blocks=nb,
+                  bytes=nb * self.block_nbytes):
+            ids = jnp.asarray(block_ids, jnp.int32)
+            S_pad = nb * self.block_size
+            k = k[:S_pad]
+            v = v[:S_pad]
+            if tier == "device":
+                self.device_pool = self._scatter_layer(
+                    self.device_pool, ids, k, v)
+            else:
+                self.host_pool = self._scatter_layer(
+                    self.host_pool, ids, k, v)
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+    @_program("kv_write")
     def _scatter_slice(self, pool, blk_ids, offs, k, v):
         """Write C tokens of one layer's KV into per-token (block, offset)
         slots — the partial-block append used by chunked prefill."""
@@ -201,18 +226,23 @@ class PagedExecutor:
     def write_layer_slice(self, tier: str, block_ids: List[int],
                           token_offset: int, k, v):
         """Append one layer's chunk KV (C, KV, hd) into `block_ids` starting
-        at absolute token `token_offset` (need not be block-aligned)."""
+        at absolute token `token_offset` (need not be block-aligned). The
+        span's `blocks` are the blocks the slice touches, its `bytes`
+        the token rows written."""
         C = k.shape[0]
-        pos = np.arange(token_offset, token_offset + C)
-        blk = jnp.asarray(np.asarray(block_ids, np.int32)
-                          [pos // self.block_size])
-        off = jnp.asarray(pos % self.block_size, jnp.int32)
-        if tier == "device":
-            self.device_pool = self._scatter_slice(
-                self.device_pool, blk, off, k, v)
-        else:
-            self.host_pool = self._scatter_slice(
-                self.host_pool, blk, off, k, v)
+        BS = self.block_size
+        with span("exec.kv.write", tier=tier,
+                  blocks=(token_offset + C - 1) // BS - token_offset // BS
+                  + 1, bytes=C * self.block_nbytes // BS):
+            pos = np.arange(token_offset, token_offset + C)
+            blk = jnp.asarray(np.asarray(block_ids, np.int32)[pos // BS])
+            off = jnp.asarray(pos % BS, jnp.int32)
+            if tier == "device":
+                self.device_pool = self._scatter_slice(
+                    self.device_pool, blk, off, k, v)
+            else:
+                self.host_pool = self._scatter_slice(
+                    self.host_pool, blk, off, k, v)
 
     def gather_layer(self, tier: str, block_ids: List[int], kv_valid=None):
         """Dense (nb*BS, KV, hd) K and V views of one layer's block list —
@@ -225,21 +255,26 @@ class PagedExecutor:
         nb = len(block_ids)
         live = nb if kv_valid is None else min(
             _round_up(kv_valid, self.block_size) // self.block_size, nb)
-        pages = pool[jnp.asarray(block_ids[:live], jnp.int32)]
-        _, _, KV, BS, hd = pool.shape
-        seq = pages.transpose(0, 3, 1, 2, 4).reshape(live * BS, 2, KV, hd)
-        k, v = seq[:, 0], seq[:, 1]
-        if live < nb:
-            pad = [(0, (nb - live) * self.block_size), (0, 0), (0, 0)]
-            k = jnp.pad(k, pad)
-            v = jnp.pad(v, pad)
-        return k, v
+        with span("exec.kv.gather", tier=tier, blocks=live,
+                  bytes=live * self.block_nbytes):
+            pages = pool[jnp.asarray(block_ids[:live], jnp.int32)]
+            _, _, KV, BS, hd = pool.shape
+            seq = pages.transpose(0, 3, 1, 2, 4).reshape(live * BS, 2, KV,
+                                                         hd)
+            k, v = seq[:, 0], seq[:, 1]
+            if live < nb:
+                pad = [(0, (nb - live) * self.block_size), (0, 0), (0, 0)]
+                k = jnp.pad(k, pad)
+                v = jnp.pad(v, pad)
+            return k, v
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=2)
+    @_program("kv_copy")
     def _copy_blocks(self, src, dst, src_ids, dst_ids):
         return dst.at[dst_ids].set(src[src_ids])
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+    @_program("kv_copy")
     def _copy_blocks_within(self, pool, src_ids, dst_ids):
         """Same-pool copy (prefix-cache COW): a separate jit so the pool
         can still be donated — passing one buffer as both src and dst of
@@ -249,24 +284,31 @@ class PagedExecutor:
     def copy_blocks(self, src_tier: str, dst_tier: str, src_ids, dst_ids):
         """Physical block copy between (or within) tiers: d2h/h2d
         transfers and d2d copy-on-write duplication."""
-        si = jnp.asarray(src_ids, jnp.int32)
-        di = jnp.asarray(dst_ids, jnp.int32)
-        if src_tier == dst_tier:
-            if src_tier == "device":
-                self.device_pool = self._copy_blocks_within(
-                    self.device_pool, si, di)
+        nb = len(src_ids)
+        with span("exec.kv.copy", src=src_tier, dst=dst_tier, blocks=nb,
+                  bytes=nb * self.block_nbytes):
+            si = jnp.asarray(src_ids, jnp.int32)
+            di = jnp.asarray(dst_ids, jnp.int32)
+            if src_tier == dst_tier:
+                if src_tier == "device":
+                    self.device_pool = self._copy_blocks_within(
+                        self.device_pool, si, di)
+                else:
+                    self.host_pool = self._copy_blocks_within(
+                        self.host_pool, si, di)
+                return
+            src = self.device_pool if src_tier == "device" \
+                else self.host_pool
+            if dst_tier == "device":
+                self.device_pool = self._copy_blocks(
+                    src, self.device_pool, si, di)
             else:
-                self.host_pool = self._copy_blocks_within(
-                    self.host_pool, si, di)
-            return
-        src = self.device_pool if src_tier == "device" else self.host_pool
-        if dst_tier == "device":
-            self.device_pool = self._copy_blocks(src, self.device_pool, si, di)
-        else:
-            self.host_pool = self._copy_blocks(src, self.host_pool, si, di)
+                self.host_pool = self._copy_blocks(
+                    src, self.host_pool, si, di)
 
     # ------------------------------------------------------- chunked prefill
     @functools.partial(jax.jit, static_argnums=0)
+    @_program("serve_chunk")
     def _chunk_forward(self, params, tokens, kbuf, vbuf, offset, kv_valid):
         """One prefill chunk at absolute token `offset` — the LEGACY
         (two-call) chunk path. tokens: (C,) int32; kbuf/vbuf: (L, S_buf,
@@ -318,15 +360,17 @@ class PagedExecutor:
         (logits, k_chunk, v_chunk); logits stay on-device (async) — the
         caller argmaxes them only on a request's FINAL chunk, so
         intermediate chunks never force a host sync."""
-        self._note_trace("chunk", (len(chunk), kbuf.shape[1]))
-        return self._chunk_forward(
-            self.params, jnp.asarray(chunk, jnp.int32), kbuf, vbuf,
-            jnp.asarray(offset, jnp.int32),
-            jnp.asarray(offset + len(chunk), jnp.int32))
+        with span("exec.chunk.launch", tokens=len(chunk)):
+            self._note_trace("chunk", (len(chunk), kbuf.shape[1]))
+            return self._chunk_forward(
+                self.params, jnp.asarray(chunk, jnp.int32), kbuf, vbuf,
+                jnp.asarray(offset, jnp.int32),
+                jnp.asarray(offset + len(chunk), jnp.int32))
 
     # ----------------------------------------------------------- fused step
     @functools.partial(jax.jit, static_argnums=(0, 18),
                        donate_argnums=(16, 17))
+    @_program("serve_mixed")
     def _mixed_forward(self, params, tokens, q_pos, off, blk_dev, blk_host,
                        c_seg, c_qpos, c_kvlens, c_tables, c_tier, d_tables,
                        d_kvlens, sample_idx, is_chunk, dpool, hpool,
@@ -404,7 +448,8 @@ class PagedExecutor:
         (n_chunks + n_decodes,) argmax'd next tokens (chunk rows are only
         meaningful for a request's final chunk)."""
         logits = self.mixed_logits(chunks, decodes)
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        with span("exec.mixed.wait"):
+            return np.asarray(jnp.argmax(logits, axis=-1))
 
     def mixed_logits(self, chunks: List[MixedChunk],
                      decodes: List[MixedDecode]) -> jax.Array:
@@ -417,85 +462,94 @@ class PagedExecutor:
         steady state reuses compiled signatures. Returns the on-device
         (n_chunks + n_decodes, V) next-token logits: a chunk's row is
         its last token's."""
-        TQ = MIXED_TQ
-        BS = self.block_size
-        L = self.cfg.n_layers
-        n_c, n_d = len(chunks), len(decodes)
-        assert n_c + n_d > 0, "mixed_step needs at least one segment"
-        pads = [_round_up(len(c.tokens), TQ) for c in chunks]
-        Tc = _bucket(sum(pads), TQ) if n_c else 0
-        Sc = _bucket(n_c) if n_c else 0
-        Rb = _bucket(n_d) if n_d else 0
-        Sb = _bucket(n_c + n_d)
-        T = Tc + Rb
-        MAXBc = _round_up(max((len(c.tables[0]) for c in chunks),
-                              default=1), 8) if n_c else 0
-        MAXBd = _round_up(max((len(d.tables[0]) for d in decodes),
-                              default=1), 8) if n_d else 0
+        with span("exec.mixed.prep", chunks=len(chunks),
+                  rows=len(decodes)) as sp:
+            TQ = MIXED_TQ
+            BS = self.block_size
+            L = self.cfg.n_layers
+            n_c, n_d = len(chunks), len(decodes)
+            assert n_c + n_d > 0, "mixed_step needs at least one segment"
+            pads = [_round_up(len(c.tokens), TQ) for c in chunks]
+            Tc = _bucket(sum(pads), TQ) if n_c else 0
+            Sc = _bucket(n_c) if n_c else 0
+            Rb = _bucket(n_d) if n_d else 0
+            Sb = _bucket(n_c + n_d)
+            T = Tc + Rb
+            MAXBc = _round_up(max((len(c.tables[0]) for c in chunks),
+                                  default=1), 8) if n_c else 0
+            MAXBd = _round_up(max((len(d.tables[0]) for d in decodes),
+                                  default=1), 8) if n_d else 0
 
-        tokens = np.zeros(T, np.int32)
-        q_pos = np.zeros(T, np.int32)
-        off = np.zeros(T, np.int32)
-        blk_dev = np.full((L, T), self.num_device_blocks, np.int32)  # trash
-        blk_host = np.full((L, T), self.num_host_blocks, np.int32)   # trash
-        c_seg = np.full(Tc, max(Sc - 1, 0), np.int32)
-        c_tables = np.zeros((L, Sc, MAXBc), np.int32)
-        c_tier = np.zeros((L, Sc), bool)
-        c_kvlens = np.zeros(Sc, np.int32)
-        d_tables = np.full((L, Rb, MAXBd), self.num_device_blocks, np.int32)
-        d_kvlens = np.zeros(Rb, np.int32)
-        sample_idx = np.zeros(Sb, np.int32)
-        is_chunk = np.zeros(Sb, bool)
+            tokens = np.zeros(T, np.int32)
+            q_pos = np.zeros(T, np.int32)
+            off = np.zeros(T, np.int32)
+            # padded rows scatter into the trash blocks
+            blk_dev = np.full((L, T), self.num_device_blocks, np.int32)
+            blk_host = np.full((L, T), self.num_host_blocks, np.int32)
+            c_seg = np.full(Tc, max(Sc - 1, 0), np.int32)
+            c_tables = np.zeros((L, Sc, MAXBc), np.int32)
+            c_tier = np.zeros((L, Sc), bool)
+            c_kvlens = np.zeros(Sc, np.int32)
+            d_tables = np.full((L, Rb, MAXBd), self.num_device_blocks,
+                               np.int32)
+            d_kvlens = np.zeros(Rb, np.int32)
+            sample_idx = np.zeros(Sb, np.int32)
+            is_chunk = np.zeros(Sb, bool)
 
-        t0 = 0
-        for i, c in enumerate(chunks):
-            C = len(c.tokens)
-            tokens[t0:t0 + C] = c.tokens
-            q_pos[t0:t0 + pads[i]] = c.offset + np.arange(pads[i])
-            c_seg[t0:t0 + pads[i]] = i
-            pos = c.offset + np.arange(C)
-            off[t0:t0 + C] = pos % BS
-            nb = len(c.tables[0])
-            for l in range(L):
-                lblk = np.asarray(c.tables[l], np.int32)
-                c_tables[l, i, :nb] = lblk
-                c_tier[l, i] = c.tiers[l]
-                dst = blk_host if c.tiers[l] else blk_dev
-                dst[l, t0:t0 + C] = lblk[pos // BS]
-            c_kvlens[i] = c.offset + C
-            sample_idx[i] = t0 + C - 1
-            is_chunk[i] = True
-            t0 += pads[i]
-        # chunk-part tail tiles: contiguous positions (a Pallas query
-        # tile's base + row arithmetic must stay valid); they map to the
-        # last chunk segment slot (a kv_len=0 dummy when Sc > n_c), write
-        # only trash, and their outputs are discarded
-        q_pos[t0:Tc] = np.arange(Tc - t0)
-        for j, d in enumerate(decodes):
-            t = Tc + j
-            tokens[t] = d.token
-            q_pos[t] = d.ctx
-            off[t] = d.ctx % BS
-            nb = len(d.tables[0])
-            for l in range(L):
-                d_tables[l, j, :nb] = d.tables[l]
-                blk_dev[l, t] = d.tables[l][d.ctx // BS]
-            d_kvlens[j] = d.ctx
-            sample_idx[n_c + j] = t
-        has_host = bool(c_tier.any())
-        self._note_trace("mixed", (Tc, Sc, Rb, Sb, MAXBc, MAXBd, has_host))
-        logits, self.device_pool, self.host_pool = self._mixed_forward(
-            self.params, jnp.asarray(tokens), jnp.asarray(q_pos),
-            jnp.asarray(off), jnp.asarray(blk_dev), jnp.asarray(blk_host),
-            jnp.asarray(c_seg), jnp.asarray(q_pos[:Tc]),
-            jnp.asarray(c_kvlens), jnp.asarray(c_tables),
-            jnp.asarray(c_tier), jnp.asarray(d_tables),
-            jnp.asarray(d_kvlens), jnp.asarray(sample_idx),
-            jnp.asarray(is_chunk), self.device_pool, self.host_pool,
-            has_host)
-        return logits[:n_c + n_d]
+            t0 = 0
+            for i, c in enumerate(chunks):
+                C = len(c.tokens)
+                tokens[t0:t0 + C] = c.tokens
+                q_pos[t0:t0 + pads[i]] = c.offset + np.arange(pads[i])
+                c_seg[t0:t0 + pads[i]] = i
+                pos = c.offset + np.arange(C)
+                off[t0:t0 + C] = pos % BS
+                nb = len(c.tables[0])
+                for l in range(L):
+                    lblk = np.asarray(c.tables[l], np.int32)
+                    c_tables[l, i, :nb] = lblk
+                    c_tier[l, i] = c.tiers[l]
+                    dst = blk_host if c.tiers[l] else blk_dev
+                    dst[l, t0:t0 + C] = lblk[pos // BS]
+                c_kvlens[i] = c.offset + C
+                sample_idx[i] = t0 + C - 1
+                is_chunk[i] = True
+                t0 += pads[i]
+            # chunk-part tail tiles: contiguous positions (a Pallas query
+            # tile's base + row arithmetic must stay valid); they map to
+            # the last chunk segment slot (a kv_len=0 dummy when Sc > n_c),
+            # write only trash, and their outputs are discarded
+            q_pos[t0:Tc] = np.arange(Tc - t0)
+            for j, d in enumerate(decodes):
+                t = Tc + j
+                tokens[t] = d.token
+                q_pos[t] = d.ctx
+                off[t] = d.ctx % BS
+                nb = len(d.tables[0])
+                for l in range(L):
+                    d_tables[l, j, :nb] = d.tables[l]
+                    blk_dev[l, t] = d.tables[l][d.ctx // BS]
+                d_kvlens[j] = d.ctx
+                sample_idx[n_c + j] = t
+            has_host = bool(c_tier.any())
+            self._note_trace("mixed",
+                             (Tc, Sc, Rb, Sb, MAXBc, MAXBd, has_host))
+            sp.set_metadata(tokens_padded=T, rows_padded=Rb)
+        with span("exec.mixed.launch"):
+            logits, self.device_pool, self.host_pool = self._mixed_forward(
+                self.params, jnp.asarray(tokens), jnp.asarray(q_pos),
+                jnp.asarray(off), jnp.asarray(blk_dev),
+                jnp.asarray(blk_host),
+                jnp.asarray(c_seg), jnp.asarray(q_pos[:Tc]),
+                jnp.asarray(c_kvlens), jnp.asarray(c_tables),
+                jnp.asarray(c_tier), jnp.asarray(d_tables),
+                jnp.asarray(d_kvlens), jnp.asarray(sample_idx),
+                jnp.asarray(is_chunk), self.device_pool, self.host_pool,
+                has_host)
+            return logits[:n_c + n_d]
 
     # --------------------------------------------------------------- decode
+    @_program("serve_decode")
     def _paged_decode(self, params, tokens, tables, kv_lens, dpool):
         """tokens: (R,) int32; tables: (L, R, MAXB) device block ids;
         kv_lens: (R,) tokens already cached. Returns (logits, dpool)."""
@@ -536,7 +590,8 @@ class PagedExecutor:
         """One decode iteration (`decode_logits`); returns the R argmax'd
         next tokens."""
         logits = self.decode_logits(tokens, tables, kv_lens)
-        return [int(t) for t in np.asarray(jnp.argmax(logits, axis=-1))]
+        with span("exec.decode.wait"):
+            return np.asarray(jnp.argmax(logits, axis=-1)).tolist()
 
     def decode_logits(self, tokens: List[int], tables: np.ndarray,
                       kv_lens: List[int]) -> jax.Array:
@@ -553,14 +608,17 @@ class PagedExecutor:
         L, _, maxb = tables.shape
         Rb = _bucket(R)
         MAXBb = _round_up(max(maxb, 1), 8)
-        self._note_trace("decode", (Rb, MAXBb))
-        toks = np.zeros(Rb, np.int32)
-        toks[:R] = tokens
-        lens = np.zeros(Rb, np.int32)
-        lens[:R] = kv_lens
-        tab = np.full((L, Rb, MAXBb), self.num_device_blocks, np.int32)
-        tab[:, :R, :maxb] = tables
-        logits, self.device_pool = self._decode_fn(
-            self.params, jnp.asarray(toks), jnp.asarray(tab),
-            jnp.asarray(lens), self.device_pool)
-        return logits[:R]
+        with span("exec.decode.prep", rows=R, rows_padded=Rb,
+                  slots_padded=L * Rb * MAXBb):
+            self._note_trace("decode", (Rb, MAXBb))
+            toks = np.zeros(Rb, np.int32)
+            toks[:R] = tokens
+            lens = np.zeros(Rb, np.int32)
+            lens[:R] = kv_lens
+            tab = np.full((L, Rb, MAXBb), self.num_device_blocks, np.int32)
+            tab[:, :R, :maxb] = tables
+        with span("exec.decode.launch"):
+            logits, self.device_pool = self._decode_fn(
+                self.params, jnp.asarray(toks), jnp.asarray(tab),
+                jnp.asarray(lens), self.device_pool)
+            return logits[:R]

@@ -42,6 +42,7 @@ from repro.core import (
 )
 from repro.core.units import Blocks, Seconds, Tokens
 from repro.obs.registry import MetricsRegistry
+from repro.obs.spans import span
 from repro.serving.costmodel import CostModel
 from repro.serving.request import Phase, Request
 
@@ -634,6 +635,14 @@ class SchedulerCore:
         through to the plain policy path. Never touches the transfer
         ledger — callers account d2h traffic at the granularity their
         step semantics require (whole-prompt vs per-chunk)."""
+        with span("sched.admit.alloc", rid=r.rid) as sp:
+            out = self._alloc_layers(r)
+            retain, off = out if out is not None else ([], [])
+            sp.set_metadata(retained=len(retain), offloaded=len(off))
+            return out
+
+    def _alloc_layers(self, r: Request) -> Optional[Tuple[list, list]]:
+        """`alloc_prefill` without its span."""
         if self.sc.prefix_cache and r.prompt:
             acq = self.bm.acquire_prefix(r.rid, r.prompt)
             if acq is not None:
@@ -852,10 +861,14 @@ class SchedulerCore:
                          now: Seconds) -> int:
         """Alg.1: how many of the ordered waiting prefills fit in the
         decode batch's minimum TPOT slack."""
-        if self.sc.policy == "layerkv" and self.sc.slo_aware:
-            return self.slo.max_prefills(order, self.decoding, now,
-                                         cached_len=self.cached_hint)
-        return len(order)
+        with span("sched.admit.budget") as sp:
+            if self.sc.policy == "layerkv" and self.sc.slo_aware:
+                budget = self.slo.max_prefills(order, self.decoding, now,
+                                               cached_len=self.cached_hint)
+            else:
+                budget = len(order)
+            sp.set_metadata(budget=budget)
+            return budget
 
     def admit_waiting(self, now: Seconds,
                       immediate: Optional[Callable[[Request], bool]] = None,
@@ -884,81 +897,85 @@ class SchedulerCore:
         (`_preempt_to_fit`) before the gate gives up.
 
         Returns the (fresh) requests admitted this pass."""
-        pool = list(self.waiting) + list(self.paused)
-        if not pool:
-            return []
-        order = self.policy.order(pool, now, self)
-        waiting_set = set(map(id, self.waiting))
-        budget_n = self.admission_budget(
-            [r for r in order if id(r) in waiting_set], now)
-        admitted: List[Request] = []
-        deferred = immediate is None and not self.sc.chunked
-        # TTFT attribution: which gate stopped this pass (head-of-line:
-        # every request still waiting afterwards waited on it)
-        stop_gate: Optional[str] = None
-        for r in order:
-            in_flight = self.in_flight() + (len(admitted) if deferred
-                                            else 0)
-            if in_flight >= self.sc.max_batch_size:
-                stop_gate = "gate:max_batch_size"
-                break
-            if id(r) not in waiting_set:
-                self._try_resume(r, now)
-                continue
-            if budget_n <= 0:
-                stop_gate = "gate:alg1_budget"
-                break
-            if token_budget is not None and admitted \
-                    and r.prompt_len > token_budget:
-                stop_gate = "gate:token_budget"
-                break
-            if self.bm.num_free(DEVICE) < self.device_need(r):
-                if not (self.sc.preemption
-                        and self._preempt_to_fit(r, now)):
-                    if self._maybe_shed(r, now):
-                        continue
-                    stop_gate = "gate:device_blocks"
+        with span("sched.admit", waiting=len(self.waiting)) as sp:
+            pool = list(self.waiting) + list(self.paused)
+            if not pool:
+                sp.set_metadata(admitted=0, stop_gate="none")
+                return []
+            order = self.policy.order(pool, now, self)
+            waiting_set = set(map(id, self.waiting))
+            budget_n = self.admission_budget(
+                [r for r in order if id(r) in waiting_set], now)
+            admitted: List[Request] = []
+            deferred = immediate is None and not self.sc.chunked
+            # TTFT attribution: which gate stopped this pass (head-of-line:
+            # every request still waiting afterwards waited on it)
+            stop_gate: Optional[str] = None
+            for r in order:
+                in_flight = self.in_flight() + (len(admitted) if deferred
+                                                else 0)
+                if in_flight >= self.sc.max_batch_size:
+                    stop_gate = "gate:max_batch_size"
                     break
-            if self.sc.chunked:
-                if self.alloc_prefill(r) is None:
-                    if self._maybe_shed(r, now):
-                        continue
-                    stop_gate = "gate:host_reserve"
+                if id(r) not in waiting_set:
+                    self._try_resume(r, now)
+                    continue
+                if budget_n <= 0:
+                    stop_gate = "gate:alg1_budget"
                     break
-                self.waiting.remove(r)
-                r.phase = Phase.PREFILL
-                r.prefill_start = now
-                self.prefilling.append(r)
-            elif immediate is not None:
-                self.waiting.remove(r)
-                # read the clock FRESH: an earlier immediate() in this
-                # pass ran a whole prefill and advanced it — stamping the
-                # pass-start `now` would under-report queueing and tie
-                # every prefill_start in the pass (breaking newest-first
-                # eviction ordering)
-                r.prefill_start = self.now
-                if not immediate(r):
-                    self.waiting.appendleft(r)
-                    if self._maybe_shed(r, now):
-                        continue
-                    stop_gate = "gate:host_reserve"
+                if token_budget is not None and admitted \
+                        and r.prompt_len > token_budget:
+                    stop_gate = "gate:token_budget"
                     break
-            else:
-                if self.alloc_prefill(r) is None:
-                    if self._maybe_shed(r, now):
-                        continue
-                    stop_gate = "gate:host_reserve"
-                    break
-                self.waiting.remove(r)
-            admitted.append(r)
-            budget_n -= 1
-            if token_budget is not None:
-                token_budget -= r.prompt_len
-        tracer = self.tracer
-        if tracer is not None:
-            tracer.sched_pass(self, now, admitted, stop_gate,
-                              immediate_mode=immediate is not None)
-        return admitted
+                if self.bm.num_free(DEVICE) < self.device_need(r):
+                    if not (self.sc.preemption
+                            and self._preempt_to_fit(r, now)):
+                        if self._maybe_shed(r, now):
+                            continue
+                        stop_gate = "gate:device_blocks"
+                        break
+                if self.sc.chunked:
+                    if self.alloc_prefill(r) is None:
+                        if self._maybe_shed(r, now):
+                            continue
+                        stop_gate = "gate:host_reserve"
+                        break
+                    self.waiting.remove(r)
+                    r.phase = Phase.PREFILL
+                    r.prefill_start = now
+                    self.prefilling.append(r)
+                elif immediate is not None:
+                    self.waiting.remove(r)
+                    # read the clock FRESH: an earlier immediate() in this
+                    # pass ran a whole prefill and advanced it — stamping the
+                    # pass-start `now` would under-report queueing and tie
+                    # every prefill_start in the pass (breaking newest-first
+                    # eviction ordering)
+                    r.prefill_start = self.now
+                    if not immediate(r):
+                        self.waiting.appendleft(r)
+                        if self._maybe_shed(r, now):
+                            continue
+                        stop_gate = "gate:host_reserve"
+                        break
+                else:
+                    if self.alloc_prefill(r) is None:
+                        if self._maybe_shed(r, now):
+                            continue
+                        stop_gate = "gate:host_reserve"
+                        break
+                    self.waiting.remove(r)
+                admitted.append(r)
+                budget_n -= 1
+                if token_budget is not None:
+                    token_budget -= r.prompt_len
+            sp.set_metadata(admitted=len(admitted),
+                            stop_gate=stop_gate or "none")
+            tracer = self.tracer
+            if tracer is not None:
+                tracer.sched_pass(self, now, admitted, stop_gate,
+                                  immediate_mode=immediate is not None)
+            return admitted
 
     # ------------------------------------------------------- chunk assembly
     def chunk_token_cap(self, now: Seconds) -> Tokens:

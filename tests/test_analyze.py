@@ -97,6 +97,24 @@ def test_model_checker_is_deterministic():
     assert runs[0][0] == 1
 
 
+def test_model_checker_explores_with_blocks(tmp_path):
+    """A phase write under a `with` block (a profiler span around a
+    scheduler method's body) is still explored: the known-bad twin with
+    `force_finish`'s body under a span trips the same QUEUED -> FINISHED
+    violation."""
+    src = (CORPUS / "mc001" / "bad" / "scheduler.py").read_text()
+    head, body = src.split("    def force_finish(self, r, now):\n")
+    f = tmp_path / "scheduler.py"
+    f.write_text(head + "    def force_finish(self, r, now):\n"
+                 + '        with span("sched.finish"):\n'
+                 + "".join("    " + ln if ln.strip() else ln
+                           for ln in body.splitlines(True)))
+    rc, out = lint("--no-cache", f)
+    assert rc == 1
+    assert [h for h in rule_hits(out, "MC001")
+            if "QUEUED -> FINISHED" in h], out
+
+
 def test_github_format_and_json():
     bad = CORPUS / "unit001" / "bad" / "accounting.py"
     rc, out = lint("--format=github", bad)

@@ -134,8 +134,9 @@ def test_sim_decomposition_exact_under_device_pressure():
                          ids=["exclusive", "chunked"])
 def test_engine_ttft_decomposition_exact(chunked):
     """Same contract on the real engine (including the exclusive
-    prefill-inside-admission path), plus wall-clock stamps on every
-    event."""
+    prefill-inside-admission path). The tracer keeps one timeline, the
+    virtual clock: the engine's wall-clock record is its profiler spans
+    (tests/test_spans.py)."""
     import dataclasses
     import jax
     from repro.configs import get_smoke_config
@@ -156,7 +157,8 @@ def test_engine_ttft_decomposition_exact(chunked):
     tr = eng.core.tracer
     assert len(tr.breakdowns()) == 6
     _assert_exact(done, tr)
-    assert all("wall" in ev for ev in tr.events)
+    assert not any("wall" in ev for ev in tr.events)
+    assert not hasattr(tr, "wall_clock")
     # executor counters live on the core's registry (one namespace)
     assert eng.ex.registry is eng.core.registry
     assert sum(eng.ex.jit_retraces.values()) \

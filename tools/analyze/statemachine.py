@@ -439,6 +439,9 @@ class _Interp:
             return out
         if isinstance(st, (ast.For, ast.While)):
             return self._loop(state, env, st)
+        if isinstance(st, ast.With):
+            # a context manager (a profiler span) runs its body once
+            return self.exec_block(state, env, st.body)
         if isinstance(st, ast.Try):
             out = []
             for leaf in self.exec_block(state, env, st.body):

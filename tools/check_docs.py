@@ -16,7 +16,8 @@ even installed. Checks:
      tools/analyze/rules.py) is documented;
   4. every trace event type and TTFT-attribution cause (the
      `EVENT_TYPES` / `ATTRIBUTION_CAUSES` tuple literals in
-     src/repro/obs/trace.py) is documented — a tracer that emits
+     src/repro/obs/trace.py) and every profiler span name (`SPAN_NAMES`
+     in src/repro/obs/spans.py) is documented — a tracer that emits
      vocabulary the docs don't explain is unreadable;
   5. every relative markdown link in the checked docs points at a file
      that exists (no rotting links).
@@ -38,6 +39,7 @@ SCHEDULER = ROOT / "src" / "repro" / "serving" / "scheduler.py"
 ROUTER = ROOT / "src" / "repro" / "serving" / "router.py"
 LINT_RULES = ROOT / "tools" / "analyze" / "rules.py"
 TRACE = ROOT / "src" / "repro" / "obs" / "trace.py"
+SPANS = ROOT / "src" / "repro" / "obs" / "spans.py"
 
 
 def serveconfig_fields(path: Path) -> list:
@@ -139,6 +141,7 @@ def main() -> int:
         "trace event type": tuple_literal(TRACE, "EVENT_TYPES"),
         "TTFT attribution cause": tuple_literal(TRACE,
                                                 "ATTRIBUTION_CAUSES"),
+        "profiler span": tuple_literal(SPANS, "SPAN_NAMES"),
     }
     errors = []
     for kind, names in required.items():
@@ -147,9 +150,9 @@ def main() -> int:
                           f"source layout assumptions in tools/check_docs.py")
         for n in names:
             # a mention must be the exact token in backticks or a table
-            # cell, not a substring of another word
-            if not re.search(rf"(?<![A-Za-z0-9_]){re.escape(n)}(?![A-Za-z0-9_])",
-                             corpus):
+            # cell, not a substring of another word or dotted name
+            if not re.search(rf"(?<![A-Za-z0-9_.]){re.escape(n)}"
+                             rf"(?![A-Za-z0-9_]|\.[A-Za-z])", corpus):
                 errors.append(f"undocumented {kind}: {n!r} "
                               f"(add it to README.md or docs/ARCHITECTURE.md)")
     for d in DOCS:
@@ -166,7 +169,8 @@ def main() -> int:
           f"{len(required['routing policy'])} routing policies, "
           f"{len(required['repro-lint rule'])} lint rules, "
           f"{len(required['trace event type'])} trace event types + "
-          f"{len(required['TTFT attribution cause'])} causes documented, "
+          f"{len(required['TTFT attribution cause'])} causes, "
+          f"{len(required['profiler span'])} profiler spans documented, "
           f"links resolve.")
     return 0
 
