@@ -10,7 +10,8 @@ compiled Pallas paged kernels on the served path.
 
 One chip:
   (a) exclusive prefill, layerkv policy, a device pool tight enough that
-      layers really offload to the host tier and reload for decode;
+      layers really offload to the host tier and reload for decode,
+      whose token KV is written in place (paged_kv_write);
   (b) chunked prefill + fused mixed step + prefix cache: paged_prefill
       (single-pool and host-tier variants) and paged_attention decode;
   logits: the fused path's prefill logits and a few decode steps' logits
@@ -283,7 +284,8 @@ def one_chip(cfg, dev, seed):
     phase_fused(cfg, params, dev, seed)
     print(f"attention implementations traced (op, impl): "
           f"{dict(ops.dispatched)}")
-    for op in ("paged_attention", "paged_prefill", "paged_prefill_tiered"):
+    for op in ("paged_attention", "paged_prefill", "paged_prefill_tiered",
+               "paged_kv_write"):
         check(ops.dispatched[op, "pallas"] > 0, f"{op} must run Pallas")
         check(ops.dispatched[op, "ref"] == 0, f"{op} fell back to the ref")
 

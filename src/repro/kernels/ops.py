@@ -1,11 +1,12 @@
 """Attention entry points, with the implementation picked by platform.
 
-On a TPU the paged ops (`paged_attention`, `paged_prefill`) run the
-compiled Pallas kernels; on any other platform they run the pure-jnp
-oracles in `ref.py`, which stay the test oracle. Dense attention
-(`flash_attention`, `decode_attention`) is always the jnp implementation,
-XLA-compiled on every platform. There is no interpreter fallback: the
-Pallas kernels interpret only when a caller passes `interpret=True`.
+On a TPU the paged ops (`paged_attention`, `paged_prefill`,
+`paged_kv_write`) run the compiled Pallas kernels; on any other
+platform they run the pure-jnp oracles in `ref.py`, which stay the test
+oracle. Dense attention (`flash_attention`, `decode_attention`) is always
+the jnp implementation, XLA-compiled on every platform. There is no
+interpreter fallback: the Pallas kernels interpret only when a caller
+passes `interpret=True`.
 
 `dispatched` counts, at trace time, which implementation each op lowered
 to, keyed by (op, impl) — a compiled step's count shows what it runs.
@@ -76,3 +77,17 @@ def paged_prefill(q, kv_pool, block_table, seg_ids, q_pos, kv_len, *,
     return _ref.paged_prefill_reference(
         q, kv_pool, block_table, seg_ids, q_pos, kv_len,
         host_pool=host_pool, tier=tier, tq=tq, softmax_scale=softmax_scale)
+
+
+def paged_kv_write(pool, blk, off, k, v):
+    """Write one token's K/V per row into the paged pool, in place: row
+    i's k/v (R, KV, hd) land at `pool[blk[i], 0/1, :, off[i]]`. Rows must
+    hit distinct (block, window) pairs but for padded rows on the trash
+    block (`kv_write.py`), as the decode step's rows do. Returns the
+    pool."""
+    impl = paged_impl()
+    dispatched["paged_kv_write", impl] += 1
+    if impl == "pallas":
+        from repro.kernels import kv_write as _kw
+        return _kw.paged_kv_write_pallas(pool, blk, off, k, v)
+    return _ref.paged_kv_write_reference(pool, blk, off, k, v)

@@ -242,3 +242,13 @@ def paged_attention_reference(q, kv_pool, block_table, kv_len, *,
     out = mha_reference(q[:, None], k, v, causal=False, kv_len=kv_len,
                         softmax_scale=softmax_scale)
     return out[:, 0]
+
+
+def paged_kv_write_reference(pool, blk, off, k, v):
+    """Write per-token KV rows k/v (N, KV, hd) into head-major pool pages
+    at (blk[i], off[i]) slots (oracle for `kv_write.py`, and the XLA
+    scatter every writer off the decode path uses). `pool[blk, 0, :,
+    off]` indexes as (N, KV, hd): the advanced indices around the head
+    slice come first."""
+    pool = pool.at[blk, 0, :, off].set(k.astype(pool.dtype))
+    return pool.at[blk, 1, :, off].set(v.astype(pool.dtype))

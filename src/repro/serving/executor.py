@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
+from repro.kernels.ref import paged_kv_write_reference as _scatter_tokens
 from repro.models import build_model, layers
 from repro.models.model import _mask_pad_logits
 from repro.obs.registry import MetricsRegistry
@@ -51,14 +52,6 @@ MIXED_TQ = 32
 
 def _round_up(n, m):
     return -(-n // m) * m
-
-
-def _scatter_tokens(pool, blk, off, k, v):
-    """Write per-token KV rows k/v (N, KV, hd) into head-major pool pages
-    at (blk[i], off[i]) slots. `pool[blk, 0, :, off]` indexes as (N, KV,
-    hd): the advanced indices around the head slice come first."""
-    pool = pool.at[blk, 0, :, off].set(k.astype(pool.dtype))
-    return pool.at[blk, 1, :, off].set(v.astype(pool.dtype))
 
 
 def _program(name: str):
@@ -568,9 +561,9 @@ class PagedExecutor:
             h = layers.apply_norm(cfg, lp["attn_norm"], x)
             q, k, v = layers.decode_self_attention(
                 cfg, lp["attn"], h, None, None, None, positions)
-            # scatter the new token's KV into its block
+            # write the new token's KV into its block, in place
             blk = tables[l][r_idx, cur_block]  # (R,)
-            dpool = _scatter_tokens(dpool, blk, cur_off, k[:, 0], v[:, 0])
+            dpool = ops.paged_kv_write(dpool, blk, cur_off, k[:, 0], v[:, 0])
             o = ops.paged_attention(q[:, 0], dpool, tables[l], kv_lens + 1)
             x = x + layers.attn_out(cfg, lp["attn"], o[:, None])
             h = layers.apply_norm(cfg, lp["mlp_norm"], x)
@@ -617,6 +610,13 @@ class PagedExecutor:
             lens[:R] = kv_lens
             tab = np.full((L, Rb, MAXBb), self.num_device_blocks, np.int32)
             tab[:, :R, :maxb] = tables
+            # the in-place KV write's contract (kv_write.py): in every
+            # layer each row writes a block of its own, or the trash block
+            cur = np.sort(tab[:, np.arange(R), lens[:R] // self.block_size],
+                          axis=1)
+            assert ((cur[:, 1:] != cur[:, :-1])
+                    | (cur[:, 1:] == self.num_device_blocks)).all(), \
+                "decode rows share a current block"
         with span("exec.decode.launch"):
             logits, self.device_pool = self._decode_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(tab),

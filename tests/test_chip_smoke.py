@@ -15,6 +15,7 @@ import jax
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import kv_write as kw
 from repro.kernels import ops
 from repro.kernels import paged_attention as pa
 from repro.kernels import paged_prefill as pp
@@ -65,6 +66,8 @@ def smoke(monkeypatch):
         pa.paged_attention_pallas, interpret=True))
     monkeypatch.setattr(pp, "paged_prefill_pallas", functools.partial(
         pp.paged_prefill_pallas, interpret=True))
+    monkeypatch.setattr(kw, "paged_kv_write_pallas", functools.partial(
+        kw.paged_kv_write_pallas, interpret=True))
     return chip_smoke
 
 

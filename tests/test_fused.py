@@ -210,6 +210,19 @@ def test_decode_bucketing_counts_retraces_once_per_bucket():
     assert ex.jit_retraces["decode"] == 2  # still no new signature
 
 
+def test_decode_rows_write_blocks_of_their_own():
+    """The in-place KV write's contract, checked on the host table: rows
+    on the trash block may share it (a warm-up decodes all rows there),
+    two rows writing one real block may not."""
+    ex, cfg = _tiny_executor()
+    L, trash = cfg.n_layers, ex.num_device_blocks
+    assert len(ex.decode([0] * 3, np.full((L, 3, 2), trash, np.int32),
+                         [0] * 3)) == 3
+    shared = np.array([[[0, 1], [2, 1]]] * L, np.int32)   # both on block 1
+    with pytest.raises(AssertionError, match="share a current block"):
+        ex.decode([1, 2], shared, [9, 9])
+
+
 def test_prefill_pad_bucketing_shares_signatures():
     ex, _ = _tiny_executor()
     ex.prefill([1, 2, 3], 8)      # bucket 16
@@ -373,6 +386,7 @@ def pallas_interpret(monkeypatch):
     chip runs it, minus the Mosaic compile."""
     import functools
 
+    from repro.kernels import kv_write as kw
     from repro.kernels import paged_attention as pa
     from repro.kernels import paged_prefill as pp
     monkeypatch.setattr(ops, "paged_impl", lambda: "pallas")
@@ -380,6 +394,8 @@ def pallas_interpret(monkeypatch):
         pa.paged_attention_pallas, interpret=True))
     monkeypatch.setattr(pp, "paged_prefill_pallas", functools.partial(
         pp.paged_prefill_pallas, interpret=True))
+    monkeypatch.setattr(kw, "paged_kv_write_pallas", functools.partial(
+        kw.paged_kv_write_pallas, interpret=True))
 
 
 @pytest.mark.slow
@@ -400,6 +416,9 @@ def test_engine_pallas_kernels_match_ref_engine(pallas_interpret):
         policy="layerkv", slo_aware=False, num_device_blocks=30,
         num_host_blocks=512, block_size=8), rng=jax.random.PRNGKey(42))
     out_px = {r.rid: r.generated for r in ex_p.run(mk())}
+    # the exclusive decode writes its token KV with the in-place kernel
+    assert ops.dispatched["paged_kv_write", "pallas"] \
+        > before.get(("paged_kv_write", "pallas"), 0)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "paged_impl", lambda: "ref")
         out_r, _ = _run_engine(cfg, mk(), ndb=30, fused=True)

@@ -8,6 +8,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_prefill import flash_attention_pallas
+from repro.kernels.kv_write import paged_kv_write_pallas, window_rows
 from repro.kernels.paged_attention import paged_attention_pallas
 
 KEY = jax.random.PRNGKey(0)
@@ -139,6 +140,53 @@ def test_paged_matches_dense_decode():
     np.testing.assert_allclose(out, expect, atol=2e-5, rtol=2e-5)
 
 
+# -------------------------------------------------------------- kv write ---
+
+def _kv_rows(pool, n_pad):
+    """Rows at offsets 0, W-1, W and BS-1 of distinct blocks, then
+    `n_pad` padded rows on the trash block (the pool's last)."""
+    NB, _, KV, BS, D = pool.shape
+    W = window_rows(pool)
+    offs = [0, W - 1, W % BS, BS - 1]
+    blk = [1, 3, 4, NB - 2] + [NB - 1] * n_pad
+    kk, kv = jax.random.split(jax.random.PRNGKey(7))
+    k = jax.random.normal(kk, (len(offs), KV, D), jnp.float32)
+    v = jax.random.normal(kv, (len(offs), KV, D), jnp.float32)
+    # padded rows carry one garbage token, as the decode step's do
+    pad = lambda a: jnp.concatenate([a, jnp.repeat(a[:1], n_pad, 0)])
+    return (jnp.array(blk, jnp.int32), jnp.array(offs + [0] * n_pad,
+                                                 jnp.int32), pad(k), pad(v))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("BS", [8, 16, 128])
+@pytest.mark.parametrize("KV,D", [(8, 64), (32, 128)])
+def test_paged_kv_write_matches_scatter(KV, D, BS, dtype):
+    """The in-place window write leaves the whole pool bit-identical to
+    the XLA scatter, trash block included."""
+    pool = jax.random.normal(KEY, (8, 2, KV, BS, D)).astype(dtype)
+    blk, off, k, v = _kv_rows(pool, n_pad=3)
+    out = paged_kv_write_pallas(pool, blk, off, k, v, interpret=True)
+    expect = ref.paged_kv_write_reference(pool, blk, off, k, v)
+    assert out.dtype == pool.dtype
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(expect, np.float32))
+    # the rows landed: nothing was written twice or dropped
+    got = np.asarray(out[blk[:4], 0, :, off[:4]], np.float32)
+    np.testing.assert_array_equal(
+        got, np.asarray(k[:4].astype(dtype), np.float32))
+
+
+def test_kv_write_window_rule():
+    """16 token rows per window for 16-bit pools, 8 for 32-bit, at most
+    the page."""
+    shape = lambda bs: (2, 2, 8, bs, 64)
+    assert window_rows(jnp.zeros(shape(128), jnp.bfloat16)) == 16
+    assert window_rows(jnp.zeros(shape(128), jnp.float32)) == 8
+    assert window_rows(jnp.zeros(shape(8), jnp.bfloat16)) == 8
+    assert window_rows(jnp.zeros(shape(16), jnp.float32)) == 8
+
+
 # --------------------------------------------------------------- rmsnorm ---
 
 @pytest.mark.parametrize("shape", [(4, 128), (2, 7, 256), (3, 33, 512)])
@@ -179,6 +227,21 @@ def test_ops_picks_ref_off_tpu():
         np.asarray(ref.paged_attention_reference(q, pool, tab, kv_len)))
 
 
+def test_ops_paged_kv_write_picks_ref_off_tpu():
+    """Off-TPU the decode step's KV write is the XLA scatter, and the
+    trace-time record says so."""
+    from repro.kernels import ops
+    pool = jax.random.normal(KEY, (8, 2, 2, 16, 32), jnp.float32)
+    blk, off, k, v = _kv_rows(pool, n_pad=0)
+    before = ops.dispatched["paged_kv_write", "ref"]
+    out = ops.paged_kv_write(pool, blk, off, k, v)
+    assert ops.dispatched["paged_kv_write", "ref"] == before + 1
+    assert ops.dispatched["paged_kv_write", "pallas"] == 0
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(ref.paged_kv_write_reference(pool, blk, off, k, v)))
+
+
 def _uninterpreted_calls():
     """One compiled-mode call per Pallas kernel, at tiny shapes."""
     from repro.kernels.paged_prefill import paged_prefill_pallas
@@ -198,6 +261,8 @@ def _uninterpreted_calls():
         "paged_prefill_tiered": lambda: paged_prefill_pallas(
             qp, pool, tab[:1], seg, pos, lens[:1], host_pool=pool,
             tier=jnp.array([True])),
+        "paged_kv_write": lambda: paged_kv_write_pallas(
+            pool, tab[0], lens, q[:, :2], q[:, :2]),
         "flash_attention": lambda: flash_attention_pallas(*qkv),
         "rmsnorm": lambda: rmsnorm_pallas(jnp.ones((8, 128)),
                                           jnp.ones((128,))),
@@ -205,7 +270,7 @@ def _uninterpreted_calls():
 
 
 @pytest.mark.parametrize("kernel", ["paged_attention", "paged_prefill",
-                                    "paged_prefill_tiered",
+                                    "paged_prefill_tiered", "paged_kv_write",
                                     "flash_attention", "rmsnorm"])
 def test_pallas_off_tpu_without_interpret_raises(kernel):
     """No kernel picks interpret mode for itself: a compiled call on a
